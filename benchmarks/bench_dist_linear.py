@@ -1,27 +1,28 @@
 """Feature-sharded lazy linear training scaling benchmark (repro.dist.linear).
 
-Weak and strong scaling of the routed-round training path over host-device
-meshes {1, 2, 4}.  Each mesh size runs in a fresh subprocess (jax locks the
-device count at first init) with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``; the workload feeds
-``route_round``-compacted per-shard blocks to ``make_routed_round_fn``
-(partial margin — the production ingestion path), with routing and
-placement excluded from the clock.
+Weak and strong scaling of the routed-round training path over meshes
+{1, 2, 4}, every mesh in the calling process over ``jax.devices()[:n]``: a
+chip belongs to one process, so no mesh size runs in a child.  The workload
+feeds ``route_round``-compacted per-shard blocks to
+``make_routed_round_fn`` (partial margin — the production ingestion path),
+with routing and placement excluded from the clock.
 
 * weak scaling: per-shard slab (ds rows) and per-shard features (q per
   example) fixed; total dim and total touched rows grow with the mesh.
 * strong scaling: total dim and features per example fixed; each shard's
   block shrinks as 1/N.
 
-This container emulates the mesh on ONE physical core, so shard programs
-serialize and raw wall time cannot show the speedup a real mesh gives.
-The reported throughput is therefore the CRITICAL-PATH rate — touched rows
-per second at wall/N, each shard's own timeline — with ``emulated: true``
-and ``physical_cores`` recorded so a real multi-core reading is
-distinguishable in the artifact.  The psum/routing overheads are genuinely
-paid in-graph either way, which is what the weak-scaling gate watches: if
-cross-shard traffic grew past the one-psum-per-step contract, the
-aggregate rate at N=4 would collapse toward 1x.
+On the CPU the four devices are host-emulated: the caller sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before the process
+starts (as the CI dist-linear job does).  Emulated shards share the host's
+cores, so raw wall time cannot show the speedup a real mesh gives; the
+reported throughput is then the CRITICAL-PATH rate — touched rows per
+second at wall/N, each shard's own timeline — with ``emulated: true`` and
+``physical_cores`` recorded so a real reading is distinguishable in the
+artifact.  The psum/routing overheads are genuinely paid in-graph either
+way, which is what the weak-scaling gate watches: if cross-shard traffic
+grew past the one-psum-per-step contract, the aggregate rate at N=4 would
+collapse toward 1x.
 
 Writes BENCH_dist_linear.json (gated by check_regression.py against
 benchmarks/baselines/); the mesh-size keys are identical in --fast and
@@ -32,11 +33,13 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
-import tempfile
 import time
-from pathlib import Path
+
+import jax
+import numpy as np
+
+from repro.core import linear_trainer as lt
+from repro.dist import linear as dl
 
 MESHES = (1, 2, 4)
 R = 64       # steps per round (one scan per round call)
@@ -47,14 +50,7 @@ P_TOTAL = 64          # strong: features per example, total (divisible by 4)
 STRONG_DIM = 4 * DS   # strong: fixed logical dim
 
 
-def _worker(mode: str, mesh: int, rounds: int, out_path: str) -> None:
-    """Runs in a fresh interpreter with the device count already forced."""
-    import numpy as np
-    import jax
-
-    from repro.core import linear_trainer as lt
-    from repro.dist import linear as dl
-
+def _measure(mode: str, mesh: int, rounds: int, emulated: bool) -> dict:
     if mode == "weak":
         dim, q = mesh * DS, Q
     else:
@@ -99,49 +95,34 @@ def _worker(mode: str, mesh: int, rounds: int, out_path: str) -> None:
     steps = rounds * R
     p_tot = P_TOTAL if mode == "strong" else n * Q
     touched = steps * B * p_tot
-    critical = elapsed / n  # emulated shards serialize on one core
-    with open(out_path, "w") as f:
-        json.dump({
-            "dim": dim, "q": q, "steps": steps, "touched_rows": touched,
-            "wall_s": elapsed, "critical_path_s": critical,
-            "touched_rows_per_s": touched / max(critical, 1e-9),
-            "us_per_step": 1e6 * critical / steps,
-        }, f)
-
-
-def _spawn(mode: str, mesh: int, rounds: int) -> dict:
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={mesh}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root), env.get("PYTHONPATH", "")]
-    )
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tf:
-        out_path = tf.name
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_dist_linear",
-             "--worker", mode, "--mesh", str(mesh),
-             "--rounds", str(rounds), "--out", out_path],
-            capture_output=True, text=True, timeout=900, env=env, cwd=root,
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"dist_linear worker {mode}/n{mesh} failed:\n{proc.stderr[-2000:]}"
-            )
-        with open(out_path) as f:
-            return json.load(f)
-    finally:
-        os.unlink(out_path)
+    critical = elapsed / n if emulated else elapsed
+    return {
+        "dim": dim, "q": q, "steps": steps, "touched_rows": touched,
+        "wall_s": elapsed, "critical_path_s": critical,
+        "touched_rows_per_s": touched / max(critical, 1e-9),
+        "us_per_step": 1e6 * critical / steps,
+    }
 
 
 def run(fast: bool = False, json_path: str = "BENCH_dist_linear.json"):
     # enough timed steps that the per-step clock window is O(seconds):
     # sub-50ms windows put scheduler noise inside the ±30% gate tolerance
     rounds = 8 if fast else 48
+    devices = jax.devices()
+    if len(devices) < max(MESHES):
+        raise RuntimeError(
+            f"dist_linear runs meshes {MESHES} in this process but only "
+            f"{len(devices)} devices are visible; on CPU set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={max(MESHES)}"
+        )
+    emulated = devices[0].platform == "cpu"
     payload = {
-        "emulated": True,
+        "emulated": emulated,
+        "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        },
         "physical_cores": os.cpu_count(),
         "workload": {
             "solver": "fobos", "margin": "partial", "round_len": R, "batch": B,
@@ -153,7 +134,7 @@ def run(fast: bool = False, json_path: str = "BENCH_dist_linear.json"):
     rows = []
     for mode in ("weak", "strong"):
         for mesh in MESHES:
-            res = _spawn(mode, mesh, rounds)
+            res = _measure(mode, mesh, rounds, emulated)
             payload[mode][str(mesh)] = res
             rows.append((
                 f"dist_linear/{mode}_n{mesh}", res["us_per_step"],
@@ -175,14 +156,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--json", default="BENCH_dist_linear.json")
-    ap.add_argument("--worker", default=None, choices=("weak", "strong"))
-    ap.add_argument("--mesh", type=int, default=None)
-    ap.add_argument("--rounds", type=int, default=6)
-    ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    if args.worker:
-        _worker(args.worker, args.mesh, args.rounds, args.out)
-        return
     print("name,us_per_call,derived")
     for name, us, derived in run(fast=args.fast, json_path=args.json):
         print(f"{name},{us:.2f},{derived}")

@@ -62,8 +62,8 @@ SUITES = {
     ),
     "dist_linear": (
         lambda a, steps: _m("bench_dist_linear").run(fast=a.fast),
-        "feature-sharded weak/strong scaling over host meshes {1,2,4} "
-        "(routed rounds, subprocess per mesh); writes BENCH_dist_linear.json",
+        "feature-sharded weak/strong scaling over meshes {1,2,4} of the "
+        "visible devices (routed rounds, one process); writes BENCH_dist_linear.json",
     ),
 }
 

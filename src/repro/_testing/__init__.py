@@ -1,0 +1,17 @@
+"""Helpers shared by the test suites."""
+
+import numpy as np
+
+
+def assert_ulp_close(actual, desired, ulps: int = 8) -> None:
+    """``|actual - desired| <= ulps`` units in the last place of the largest
+    ``|desired|`` (float32).
+
+    For two programs that compute the same f32 arithmetic but fuse it
+    differently: each rounding differs by an ulp of its operands, and after
+    cancellation (a weight shrunk toward zero) that is many ulps of a small
+    result, so an element-wise ulp bound is the wrong measure there."""
+    actual = np.asarray(actual, np.float32)
+    desired = np.asarray(desired, np.float32)
+    scale = np.max(np.abs(desired)) if desired.size else np.float32(0.0)
+    np.testing.assert_allclose(actual, desired, rtol=0, atol=ulps * np.spacing(scale))

@@ -1,6 +1,8 @@
 """Roofline analysis over the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the per-chip peaks of :data:`PEAKS`, keyed by jax's
+``device_kind``; the dry-run's production meshes model TPU v5e pods
+(:data:`DRYRUN_KIND`).
 
 Per (arch x shape x mesh) cell, from the compiled (post-SPMD, per-device)
 module:
@@ -24,9 +26,27 @@ from typing import Dict, Optional
 
 import numpy as np
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s / link
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect per chip
+# (4 links of 50 GB/s).  A kind missing here is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+#: the chip the dry-run's production meshes (launch/mesh.py) model
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak bf16 FLOP/s, HBM B/s and per-link ICI B/s of one chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)} (add it to PEAKS with its source)"
+        ) from None
+
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
@@ -62,9 +82,10 @@ def analyze_cell(rec: dict, n_active: Optional[int] = None) -> Optional[dict]:
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     coll = rec.get("collective_bytes") or {}
     coll_total = float(sum(coll.values()))
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_acc / HBM_BW
-    collective_s = coll_total / ICI_BW
+    peak = peaks(DRYRUN_KIND)
+    compute_s = flops / peak["flops"]
+    memory_s = bytes_acc / peak["hbm_bw"]
+    collective_s = coll_total / peak["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())

@@ -8,7 +8,6 @@ arbitrary position vectors, explicit validity masks) fall back to the
 reference einsum path."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.dp_caches import FOBOS, SGD
@@ -25,6 +24,7 @@ from repro.kernels import (
     lazy_enet_update,
     screen_mask,
 )
+from repro.kernels.common import default_interpret
 from repro.kernels.flash_attn import flash_attention
 
 from .api import KernelBackend
@@ -145,6 +145,6 @@ class PallasBackend(KernelBackend):
             causal=causal,
             block_q=block_q,
             block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
+            interpret=default_interpret(),
         )
         return out.transpose(0, 2, 1, 3)

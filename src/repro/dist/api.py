@@ -80,16 +80,12 @@ def shard(x, *logical_axes: Optional[str]):
 
 
 def manual_shard_map(fn, mesh, in_specs, out_specs, *, manual_axes):
-    """Version-tolerant partially-manual shard_map: the axes in
-    ``manual_axes`` become manual (collectives by name), every other mesh
-    axis stays automatic so GSPMD partitions the body exactly like the
-    surrounding jit region.  Used by the cross-pod gradient compression
-    (dist/compress.py), where only "pod" is manual."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:  # jax >= 0.6 spelling
-        return sm(fn, axis_names=set(manual_axes), check_vma=False, **kwargs)
-    from jax.experimental.shard_map import shard_map as sm
-
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return sm(fn, check_rep=False, auto=auto, **kwargs)
+    """Partially-manual shard_map: the axes in ``manual_axes`` become manual
+    (collectives by name), every other mesh axis stays automatic so GSPMD
+    partitions the body exactly like the surrounding jit region.  Used by
+    the cross-pod gradient compression (dist/compress.py), where only "pod"
+    is manual, and by the feature-sharded linear paths (dist/linear.py)."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(manual_axes), check_vma=False,
+    )

@@ -18,11 +18,23 @@ the plumbing lives here once:
   over a 2-D grid.
 * :func:`row_tile_spec` — a ``(block_rows, 1)`` per-row operand (one scalar
   per sublane, broadcast across lanes by the VPU).
+* :func:`default_interpret` — the one place a kernel call picks interpret
+  mode (off the TPU) or the compiled kernel.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def default_interpret() -> bool:
+    """Interpret mode wherever the default backend is not a TPU (the CPU
+    test runs), the compiled kernel on it.  A run that expects the chip
+    checks its lowered programs for the compiled call (``chip_smoke.py``
+    looks for ``tpu_custom_call``) rather than trusting this switch."""
+    return jax.default_backend() != "tpu"
+
 
 #: (1, 1) scalar operand mapped to every program of any grid rank
 SCALAR_SPEC = pl.BlockSpec((1, 1), lambda *_: (0, 0))

@@ -43,7 +43,8 @@ integer input, so its cotangent is the symbolic float0 zero.
 
 Validated under interpret=True against the pure-jnp GQA oracle across
 shape/dtype/causality sweeps, and the vjp against jax.grad of that oracle
-(tests/kernels/test_flash_attn.py).
+(tests/kernels/test_flash_attn.py); forward and backward compile for TPU
+v5e at stablelm_3b widths (tests/kernels/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -54,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -64,14 +66,36 @@ def _kv_index_map(KV: int, G: int):
     return lambda bh, nq: ((bh // (G * KV)) * KV + (bh % (G * KV)) // G, 0, 0)
 
 
+# Mosaic tiling rules (last two block dims divisible by (8, 128) or equal
+# to the array's) shape the operands: the per-program q offset is a whole
+# (B*H,) int32 array in SMEM read at program_id(0), and the per-row
+# residuals lse / delta are [B*H, Sq_p, 1] columns, so every in-kernel
+# value stays a 2-D [rows, 1] or [rows, cols] tile.
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _mask(q_pos, kv_pos, causal: bool, skv_real: int):
+    mask = kv_pos < skv_real  # padded kv rows never score
+    if causal:
+        mask = mask & (kv_pos <= q_pos)
+    return mask
+
+
+def _positions(q_start, bq: int, kv_start, bk: int):
+    """Absolute [bq, bk] q / kv position grids of one score tile."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return q_pos, kv_pos
+
+
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, qoff_ref, out_ref, lse_ref, *, bk: int, causal: bool, scale: float, skv_real: int
+    qoff_ref, q_ref, k_ref, v_ref, out_ref, lse_ref, *, bk: int, causal: bool, scale: float, skv_real: int
 ):
     q = q_ref[0].astype(jnp.float32) * scale  # [BQ, hd]
     BQ = q.shape[0]
     Skv = k_ref.shape[1]
-    nq = pl.program_id(1)
-    q_pos = qoff_ref[0, 0] + nq * BQ + jax.lax.iota(jnp.int32, BQ)  # absolute q positions
+    # read outside the loop bodies: the interpreter resolves program_id only there
+    q_start = qoff_ref[pl.program_id(0)] + pl.program_id(1) * BQ
 
     def body(i, carry):
         acc, m, den = carry
@@ -80,42 +104,39 @@ def _fwd_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [BQ, BK]
-        kv_pos = i * bk + jax.lax.iota(jnp.int32, bk)
-        mask = (kv_pos < skv_real)[None, :]  # padded kv rows never score
-        if causal:
-            mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
+        q_pos, kv_pos = _positions(q_start, BQ, i * bk, bk)
+        s = jnp.where(_mask(q_pos, kv_pos, causal, skv_real), s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [BQ, 1]
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        den_new = den * alpha + jnp.sum(p, axis=1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_new)
+        den_new = den * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         return acc_new, m_new, den_new
 
     acc0 = jnp.zeros((BQ, q.shape[1]), jnp.float32)
-    m0 = jnp.full((BQ,), NEG_INF, jnp.float32)
-    den0 = jnp.zeros((BQ,), jnp.float32)
+    m0 = jnp.full((BQ, 1), NEG_INF, jnp.float32)
+    den0 = jnp.zeros((BQ, 1), jnp.float32)
     acc, m, den = jax.lax.fori_loop(0, Skv // bk, body, (acc0, m0, den0))
-    out = acc / jnp.maximum(den, 1e-30)[:, None]
-    out_ref[0] = out.astype(out_ref.dtype)
+    den = jnp.maximum(den, 1e-30)
+    out_ref[0] = (acc / den).astype(out_ref.dtype)
     # log-sum-exp of the (scaled, masked) scores — the backward's residual
-    lse_ref[0] = m + jnp.log(jnp.maximum(den, 1e-30))
+    lse_ref[0] = m + jnp.log(den)
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qoff_ref, dq_ref,
+    qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     *, bk: int, causal: bool, scale: float, skv_real: int,
 ):
     q = q_ref[0].astype(jnp.float32) * scale  # [BQ, hd] (scaled like forward)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]  # [BQ]
-    delta = delta_ref[0]  # [BQ]
+    lse = lse_ref[0]  # [BQ, 1]
+    delta = delta_ref[0]  # [BQ, 1]
     BQ = q.shape[0]
     Skv = k_ref.shape[1]
-    nq = pl.program_id(1)
-    q_pos = qoff_ref[0, 0] + nq * BQ + jax.lax.iota(jnp.int32, BQ)
+    # read outside the loop bodies: the interpreter resolves program_id only there
+    q_start = qoff_ref[pl.program_id(0)] + pl.program_id(1) * BQ
 
     def body(i, dq):
         k = k_ref[0, pl.dslice(i * bk, bk)].astype(jnp.float32)
@@ -123,15 +144,13 @@ def _dq_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        kv_pos = i * bk + jax.lax.iota(jnp.int32, bk)
-        mask = (kv_pos < skv_real)[None, :]
-        if causal:
-            mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)  # [BQ, BK]
+        q_pos, kv_pos = _positions(q_start, BQ, i * bk, bk)
+        mask = _mask(q_pos, kv_pos, causal, skv_real)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [BQ, BK]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return dq + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -142,31 +161,28 @@ def _dq_kernel(
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qoff_ref, dk_ref, dv_ref,
+    qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     *, bq: int, causal: bool, scale: float, skv_real: int,
 ):
     k = k_ref[0].astype(jnp.float32)  # [BK, hd] — this program's kv tile
     v = v_ref[0].astype(jnp.float32)
     BK = k.shape[0]
     Sq = q_ref.shape[1]
-    nk = pl.program_id(1)
-    kv_pos = nk * BK + jax.lax.iota(jnp.int32, BK)
-    qoff = qoff_ref[0, 0]
+    qoff = qoff_ref[pl.program_id(0)]
+    kv_start = pl.program_id(1) * BK
 
     def body(i, carry):
         dk, dv = carry
         q = q_ref[0, pl.dslice(i * bq, bq)].astype(jnp.float32) * scale  # [BQ, hd]
         do = do_ref[0, pl.dslice(i * bq, bq)].astype(jnp.float32)
-        lse = lse_ref[0, pl.dslice(i * bq, bq)]
+        lse = lse_ref[0, pl.dslice(i * bq, bq)]  # [BQ, 1]
         delta = delta_ref[0, pl.dslice(i * bq, bq)]
-        q_pos = qoff + i * bq + jax.lax.iota(jnp.int32, bq)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [BQ, BK]
-        mask = (kv_pos < skv_real)[None, :]
-        if causal:
-            mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        q_pos, kv_pos = _positions(qoff + i * bq, bq, kv_start, BK)
+        mask = _mask(q_pos, kv_pos, causal, skv_real)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         # dv += p^T @ do  (padded q rows: do = 0 -> zero contribution)
         dv_new = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -174,7 +190,7 @@ def _dkv_kernel(
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         # dk += ds^T @ (q * scale) — q is pre-scaled, so scale is included
         dk_new = dk + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -204,14 +220,14 @@ def _pad_qkv(q, k, v, block_q, block_k):
 
 
 def _fwd_impl(q, k, v, q_offset, causal, block_q, block_k, interpret):
-    """Padded forward; returns (out [B,H,Sq,hd], lse [B*H, Sq_p])."""
+    """Padded forward; returns (out [B,H,Sq,hd], lse [B*H, Sq_p, 1])."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     q2, k2, v2 = _pad_qkv(q, k, v, block_q, block_k)
     Sq_p, Skv_p = q2.shape[1], k2.shape[1]
-    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B * H,)).reshape(B * H, 1)
+    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B * H,))
     grid = (B * H, Sq_p // block_q)
     kv_map = _kv_index_map(KV, G)
 
@@ -219,21 +235,21 @@ def _fwd_impl(q, k, v, q_offset, causal, block_q, block_k, interpret):
         functools.partial(_fwd_kernel, bk=block_k, causal=causal, scale=scale, skv_real=Skv),
         grid=grid,
         in_specs=[
+            _SMEM_SPEC,  # q offsets
             pl.BlockSpec((1, block_q, hd), lambda bh, nq: (bh, nq, 0)),  # q tile
             pl.BlockSpec((1, Skv_p, hd), kv_map),
             pl.BlockSpec((1, Skv_p, hd), kv_map),
-            pl.BlockSpec((1, 1), lambda bh, nq: (bh, 0)),  # q_offset scalar
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, nq: (bh, nq, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, nq: (bh, nq)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, nq: (bh, nq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq_p, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, Sq_p, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q2, k2, v2, offs)
+    )(offs, q2, k2, v2)
 
     return out.reshape(B, H, Sq_p, hd)[:, :, :Sq], lse
 
@@ -260,43 +276,42 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     # delta = rowsum(do * out): a cheap XLA reduction over the unpadded
     # arrays; zero-padding do/delta keeps padded q rows inert in-kernel
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, H, Sq]
-    delta2 = jnp.pad(delta, ((0, 0), (0, 0), (0, Sq_p - Sq))).reshape(B * H, Sq_p)
+    delta2 = jnp.pad(delta, ((0, 0), (0, 0), (0, Sq_p - Sq))).reshape(B * H, Sq_p, 1)
     do2 = jnp.pad(do, ((0, 0), (0, 0), (0, Sq_p - Sq), (0, 0))).reshape(B * H, Sq_p, hd)
-    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B * H,)).reshape(B * H, 1)
+    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B * H,))
     kv_map = _kv_index_map(KV, G)
     qmap = lambda bh, nq: (bh, nq, 0)
-    rowmap = lambda bh, nq: (bh, nq)
     slabmap = lambda bh, nk: (bh, 0, 0)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bk=block_k, causal=causal, scale=scale, skv_real=Skv),
         grid=(B * H, Sq_p // block_q),
         in_specs=[
+            _SMEM_SPEC,  # q offsets
             pl.BlockSpec((1, block_q, hd), qmap),  # q tile
             pl.BlockSpec((1, Skv_p, hd), kv_map),
             pl.BlockSpec((1, Skv_p, hd), kv_map),
             pl.BlockSpec((1, block_q, hd), qmap),  # do tile
-            pl.BlockSpec((1, block_q), rowmap),  # lse tile
-            pl.BlockSpec((1, block_q), rowmap),  # delta tile
-            pl.BlockSpec((1, 1), lambda bh, nq: (bh, 0)),
+            pl.BlockSpec((1, block_q, 1), qmap),  # lse tile
+            pl.BlockSpec((1, block_q, 1), qmap),  # delta tile
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), qmap),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, hd), q.dtype),
         interpret=interpret,
-    )(q2, k2, v2, do2, lse, delta2, offs)
+    )(offs, q2, k2, v2, do2, lse, delta2)
 
     kv_tile = lambda bh, nk, KV=KV, G=G: ((bh // (G * KV)) * KV + (bh % (G * KV)) // G, nk, 0)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=block_q, causal=causal, scale=scale, skv_real=Skv),
         grid=(B * H, Skv_p // block_k),
         in_specs=[
+            _SMEM_SPEC,  # q offsets
             pl.BlockSpec((1, Sq_p, hd), slabmap),  # q slab
             pl.BlockSpec((1, block_k, hd), kv_tile),  # k tile
             pl.BlockSpec((1, block_k, hd), kv_tile),  # v tile
             pl.BlockSpec((1, Sq_p, hd), slabmap),  # do slab
-            pl.BlockSpec((1, Sq_p), lambda bh, nk: (bh, 0)),  # lse slab
-            pl.BlockSpec((1, Sq_p), lambda bh, nk: (bh, 0)),  # delta slab
-            pl.BlockSpec((1, 1), lambda bh, nk: (bh, 0)),
+            pl.BlockSpec((1, Sq_p, 1), slabmap),  # lse slab
+            pl.BlockSpec((1, Sq_p, 1), slabmap),  # delta slab
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd), lambda bh, nk: (bh, nk, 0)),
@@ -307,7 +322,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
             jax.ShapeDtypeStruct((B * H, Skv_p, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q2, k2, v2, do2, lse, delta2, offs)
+    )(offs, q2, k2, v2, do2, lse, delta2)
 
     dq = dq.reshape(B, H, Sq_p, hd)[:, :, :Sq]
     # GQA: per-q-head dk/dv partials reduce over the group of G q-heads

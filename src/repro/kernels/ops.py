@@ -2,7 +2,8 @@
 
 These handle: deriving per-row catch-up factors from the DP caches, padding
 ragged shapes to hardware-aligned block multiples, 1-D <-> 2-D reshaping,
-and interpret-mode fallback on CPU (this container) vs compiled mode on TPU.
+and the interpret-mode switch (``common.default_interpret``: interpret off
+the TPU, compiled on it).
 
 Hyperparameters (``lam1``, ``eta``, the prox ``a``/``s``) are DYNAMIC f32
 operands, never static: they only enter through the catch-up factors / shift
@@ -22,16 +23,13 @@ import jax.numpy as jnp
 from repro.core.dp_caches import RegCaches
 from repro.core.lazy_enet import catchup_factors
 
+from .common import default_interpret
 from .enet_prox import enet_prox_kernel
 from .ftrl import ftrl_read_rows_kernel, ftrl_update_rows_kernel
 from .fused_step import dp_fused_step_kernel, ftrl_fused_step_kernel
 from .lazy_enet import enet_apply_rows_kernel, lazy_enet_rows_kernel
 from .margin import dp_margin_rows_kernel, ftrl_margin_rows_kernel
 from .screen import screen_rows_kernel
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
@@ -70,7 +68,7 @@ def lazy_enet_update(
 
     Padding is safe: padded w=grad=0 rows/cols produce 0 (sign(0)=0)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     R, D = w_rows.shape
     ratio, shift = catchup_factors(psi, k, caches, lam1)  # [R] f32 each
     ratio = jnp.broadcast_to(ratio, (R,))
@@ -108,7 +106,7 @@ def enet_apply(
     * scalar factors broadcast over either layout.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     if w.ndim == 2:
         R, D = w.shape
         wp = _pad_to(w, block_rows, block_cols)
@@ -178,7 +176,7 @@ def ftrl_read(
     """Apply-at-read FTRL-Proximal weights from flat ``(z, n)`` state —
     the solver's elastic-net closed form, shape-preserving."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     assert z.ndim == 1 and z.shape == n.shape, (z.shape, n.shape)
     cnt = z.shape[0]
     z2 = _tile_flat(z, block_rows, block_cols)
@@ -204,7 +202,7 @@ def ftrl_update(
     """Per-coordinate AdaGrad FTRL update deltas ``(dz, dn)`` — the caller
     scatter-ADDs them so duplicate indices keep additive semantics in XLA."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     assert w.ndim == 1 and w.shape == n.shape == g.shape, (w.shape, n.shape, g.shape)
     cnt = w.shape[0]
     w2 = _tile_flat(w, block_rows, block_cols)
@@ -229,7 +227,7 @@ def enet_prox(
 ):
     """Dense elastic-net shrink sweep, shape-preserving."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     shape = w.shape
     flat = w.reshape(-1)
     n = flat.shape[0]
@@ -256,7 +254,7 @@ def dp_margin(
     catch-up + margin contributions in one elementwise pass.  Padding is
     safe (w = val = 0 -> 0 outputs).  Returns ``(w_cur, contrib)`` [B, p]."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, p = w.shape
     w_cur, contrib = dp_margin_rows_kernel(
         _pad_to(w, block_rows, block_cols), _pad_to(ratio, block_rows, block_cols),
@@ -283,7 +281,7 @@ def ftrl_margin(
     """Shard-local pre-psum half of the fused FTRL step: apply-at-read +
     margin contributions.  Returns ``(w_cur, contrib)`` [B, p]."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, p = z.shape
     w_cur, contrib = ftrl_margin_rows_kernel(
         _pad_to(z, block_rows, block_cols), _pad_to(n, block_rows, block_cols),
@@ -309,7 +307,7 @@ def screen_mask(
     ``viol = ~active & (|g| > chk)``.  Comparisons only — exactly equal to
     the reference twin, never merely close."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     assert g.ndim == 1 and g.shape == w.shape, (g.shape, w.shape)
     cnt = g.shape[0]
     g2 = _tile_flat(g, block_rows, block_cols)
@@ -356,7 +354,7 @@ def dp_fused_step(
     padded example rows are sliced off here.  Returns
     ``(w_cur [B, p], delta [B, p], gz [B], loss [B])``."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, p = w.shape
     Bp, P = _step_dims(B, p, block_rows)
     y2 = jnp.pad(y.reshape(B, 1).astype(jnp.float32), ((0, Bp - B), (0, 0)))
@@ -390,7 +388,7 @@ def ftrl_fused_step(
     z = n = val = 0 and produce w_cur = dz = dn = 0 exactly.  Returns
     ``(w_cur [B, p], dz [B, p], dn [B, p], gz [B], loss [B])``."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, p = z.shape
     Bp, P = _step_dims(B, p, block_rows)
     y2 = jnp.pad(y.reshape(B, 1).astype(jnp.float32), ((0, Bp - B), (0, 0)))

@@ -33,6 +33,7 @@ from repro.dist.sharding import (  # noqa: E402
     train_state_axes,
 )
 from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 from repro.models import params as pp  # noqa: E402
 from repro.train import make_train_step  # noqa: E402
 
@@ -255,6 +256,7 @@ def main():
              "of the full-depth dry-run; writes calib__*.json",
     )
     args = ap.parse_args()
+    compile_cache.enable()
 
     RESULTS_DIR.mkdir(parents=True, exist_ok=True)
     meshes = ["pod", "multipod"] if args.mesh == "both" else [args.mesh]

@@ -14,7 +14,8 @@ import dataclasses  # noqa: E402
 import json  # noqa: E402
 from pathlib import Path  # noqa: E402
 
-from repro.analysis.roofline import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: E402
+from repro.analysis.roofline import DRYRUN_KIND, peaks  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "hillclimb"
 
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--hlo", action="store_true", help="dump op-level histogram of the 1-layer unrolled module")
     ap.add_argument("--mem", action="store_true", help="also lower the FULL-depth module and print memory_analysis (peak temp)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.analysis import calibrate as cal
     from repro.analysis.hlo_ops import report
@@ -64,9 +66,10 @@ def main():
     res = cal.calibrated_cell(args.arch, args.shape, multi)
     cost = res["cost_analysis"]
     coll = sum(res["collective_bytes"].values())
-    compute_s = cost["flops"] / PEAK_FLOPS
-    memory_s = cost["bytes accessed"] / HBM_BW
-    collective_s = coll / ICI_BW
+    peak = peaks(DRYRUN_KIND)
+    compute_s = cost["flops"] / peak["flops"]
+    memory_s = cost["bytes accessed"] / peak["hbm_bw"]
+    collective_s = coll / peak["ici_bw"]
     print(f"== {args.arch} x {args.shape} x {args.mesh}  patch={patch}")
     print(f"   flops/dev {cost['flops']:.3e}  -> compute  {compute_s*1e3:10.1f} ms")
     print(f"   bytes/dev {cost['bytes accessed']:.3e}  -> memory   {memory_s*1e3:10.1f} ms")

@@ -13,8 +13,7 @@ import numpy as np
 def _mesh(shape, axes):
     """Build a Mesh over the first prod(shape) devices.  Explicit device
     slicing (rather than jax.make_mesh) so the 512 host-platform placeholder
-    devices the dry-run forces can carry a 256-chip single-pod mesh, and so
-    construction works across jax versions (axis_types landed after 0.4)."""
+    devices the dry-run forces can carry a 256-chip single-pod mesh."""
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) < n:

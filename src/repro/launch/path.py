@@ -26,7 +26,7 @@ from repro import obs, paths
 from repro import solvers as solver_registry
 from repro.core import LinearConfig, ScheduleConfig, SparseBatch
 from repro.data import BowConfig, SyntheticBow
-from repro.launch import flags
+from repro.launch import compile_cache, flags
 from repro.launch.sweep import parse_grid
 from repro.serving import LinearService, ServiceConfig
 from repro.sweeps import log_ladder, make_grid
@@ -113,6 +113,7 @@ def main() -> None:
     )
     flags.add_profile(ap, help="collect a jax profiler trace of the path into DIR")
     args = ap.parse_args()
+    compile_cache.enable()
 
     n1, n2 = parse_grid(args.grid)
     solvers = None

@@ -39,6 +39,7 @@ from repro.configs import get_arch
 from repro.dist import api as dist_api
 from repro.dist import sharding as dist_sharding
 from repro.launch.mesh import host_mesh_from_spec
+from repro.launch import compile_cache
 from repro.models import build, init_params
 from repro.models import params as pp
 from repro.serving import EngineConfig, ServeEngine, ServingMetrics
@@ -120,10 +121,13 @@ def serve_engine(cfg, model, params, *, batch, prompt_len, new_tokens, seed=0,
 
 def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch=8,
                  requests=256, round_len=256, seed=0, fused=None, state_dtype="f32",
-                 mesh=None):
+                 mesh=None, eta0=0.3):
     """Online learn/predict smoke over the LinearService: warm the complete
     jit set (every power-of-two bucket x {learn, predict} + the round
     flush), then stream ``requests`` examples and assert zero recompiles.
+    Requests carry ``p_max / 2`` features on average; ``eta0`` = 0.3 suits
+    the default 16, while ~64 (``p_max=128``) needs a smaller rate, or
+    plain SGD on the hot features diverges.
 
     ``mesh=N`` feature-shards the packed solver state across N devices
     (repro.dist.linear); the same zero-recompile assertion holds — routing
@@ -134,7 +138,7 @@ def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch
 
     cfg = LinearConfig(
         dim=dim, round_len=round_len, lam1=1e-5, lam2=1e-6,
-        schedule=ScheduleConfig(kind="inv_sqrt", eta0=0.3, t0=100.0),
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=eta0, t0=100.0),
         fused=fused, state_dtype=state_dtype, mesh=mesh,
     )
     svc = LinearService(cfg, ServiceConfig(
@@ -356,6 +360,7 @@ def main():
     flags.add_metrics_out(ap)
     flags.add_profile(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     if args.linear:
         mesh = None
         if args.mesh is not None:

@@ -24,7 +24,7 @@ from repro import obs
 from repro import solvers as solver_registry
 from repro.core import LinearConfig, ScheduleConfig, SparseBatch
 from repro.data import BowConfig, SyntheticBow
-from repro.launch import flags
+from repro.launch import compile_cache, flags
 from repro.serving import LinearService, ServiceConfig
 from repro.sweeps import kfold_cv, log_ladder, make_grid
 
@@ -99,6 +99,7 @@ def main() -> None:
     )
     flags.add_profile(ap, help="collect a jax profiler trace of the sweep into DIR")
     args = ap.parse_args()
+    compile_cache.enable()
 
     n1, n2 = parse_grid(args.grid)
     solvers = None

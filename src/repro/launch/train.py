@@ -34,7 +34,7 @@ from repro import backend as kernel_backend
 from repro import obs
 from repro import solvers
 from repro.checkpoint import checkpointer
-from repro.launch import flags
+from repro.launch import compile_cache, flags
 from repro.configs import get_arch
 from repro.data import LMDataConfig, SyntheticLMData
 from repro.dist import sharding as dist_sharding
@@ -255,6 +255,7 @@ def main():
     )
     flags.add_profile(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     d = get_arch(args.arch)
     if args.reduced:
         d = d.reduced()

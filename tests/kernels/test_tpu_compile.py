@@ -1,0 +1,130 @@
+"""Compile-only checks: the Pallas kernels of the main paths compile for a
+described TPU v5e chip at real widths, with no chip attached.
+
+Each case lowers the public kernel wrapper with ``interpret=False`` against
+shapes placed on one device of a described ``v5e:2x2`` topology and asserts
+that the compiled program holds the Mosaic call (``tpu_custom_call``).  The
+TPU compiler refuses here what interpret mode accepts: block shapes that
+break the (8, 128) tiling, or more VMEM than a kernel may use.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every test worker imports this file.
+All cases live in this one file so that a single worker loads it.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.flash_attn import flash_attention
+
+MEDLINE_DIM = 260_941
+HASHED_DIM = 2**24
+# stablelm_3b at its published widths: 32 heads of 80
+HEADS, HEAD_DIM = 32, 80
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: entries
+    written for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _fused_dp(s):
+    bp = _f32(s, 32, 128)
+    return ops.dp_fused_step.lower(
+        bp, bp, bp, bp, _f32(s, 32), 0.0, 0.1, loss="logistic", use_bias=True, interpret=False
+    )
+
+
+def _fused_ftrl(s):
+    bp = _f32(s, 32, 128)
+    return ops.ftrl_fused_step.lower(
+        bp, bp, bp, _f32(s, 32), 0.0, 0.1, 1.0, 1e-5, 1e-6,
+        loss="logistic", use_bias=True, interpret=False,
+    )
+
+
+def _screen(s):
+    g = _f32(s, MEDLINE_DIM)
+    return ops.screen_mask.lower(g, g, 0.1, 0.2, interpret=False)
+
+
+def _enet_apply(s):
+    w = _f32(s, HASHED_DIM)
+    return ops.enet_apply.lower(w, w, w, interpret=False)
+
+
+def _dp_margin(s):
+    bp = _f32(s, 32, 128)
+    return ops.dp_margin.lower(bp, bp, bp, bp, interpret=False)
+
+
+def _flash(s, *, batch, sq, skv, block_q, block_k, offset, grad=False):
+    q = jax.ShapeDtypeStruct((batch, HEADS, sq, HEAD_DIM), jnp.bfloat16, sharding=s)
+    kv = jax.ShapeDtypeStruct((batch, HEADS, skv, HEAD_DIM), jnp.bfloat16, sharding=s)
+    attn = functools.partial(
+        flash_attention, causal=True, block_q=block_q, block_k=block_k, interpret=False
+    )
+    if grad:
+
+        def loss(q, k, v):
+            return attn(q, k, v, offset).astype(jnp.float32).sum()
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
+    if offset is None:  # decode: one absolute position per (batch, head) program
+        offs = jax.ShapeDtypeStruct((batch * HEADS,), jnp.int32, sharding=s)
+        return jax.jit(attn).lower(q, kv, kv, offs)
+    return jax.jit(lambda q, k, v: attn(q, k, v, offset)).lower(q, kv, kv)
+
+
+CASES = {
+    "dp_fused_step": _fused_dp,
+    "ftrl_fused_step": _fused_ftrl,
+    "screen_mask": _screen,
+    "enet_apply": _enet_apply,
+    "dp_margin": _dp_margin,
+    "flash_prefill": functools.partial(
+        _flash, batch=1, sq=256, skv=256, block_q=128, block_k=128, offset=0
+    ),
+    "flash_decode": functools.partial(
+        _flash, batch=8, sq=1, skv=64, block_q=8, block_k=64, offset=None
+    ),
+    "flash_prefill_backward": functools.partial(
+        _flash, batch=1, sq=256, skv=256, block_q=128, block_k=128, offset=0, grad=True
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    compiled = CASES[case](one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()

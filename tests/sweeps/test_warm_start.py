@@ -5,6 +5,7 @@ inside the previous optimum's basin)."""
 
 import numpy as np
 
+from repro._testing import assert_ulp_close
 from repro.core import LinearConfig, ScheduleConfig
 from repro.data import BowConfig, SyntheticBow
 from repro.sweeps import log_ladder, make_grid, run_grid, run_path
@@ -46,9 +47,10 @@ def test_cold_path_equals_grid_fit():
     rounds = _bow_rounds(2, base.round_len, 2)
     cold = run_path(grid, rounds, warm_start=False)
     bstate, losses = run_grid(grid, rounds)
-    np.testing.assert_array_equal(cold.weights, np.asarray(bstate.wpsi[:, :, 0]))
-    np.testing.assert_array_equal(cold.b, np.asarray(bstate.b))
-    np.testing.assert_array_equal(cold.losses, losses)
+    # the batched and plain programs fuse differently under this XLA
+    assert_ulp_close(cold.weights, np.asarray(bstate.wpsi[:, :, 0]))
+    assert_ulp_close(cold.b, np.asarray(bstate.b))
+    assert_ulp_close(cold.losses, losses)
 
 
 def test_warm_start_beats_cold_start_on_lam1_path():
