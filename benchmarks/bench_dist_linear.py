@@ -17,12 +17,20 @@ On the CPU the four devices are host-emulated: the caller sets
 starts (as the CI dist-linear job does).  Emulated shards share the host's
 cores, so raw wall time cannot show the speedup a real mesh gives; the
 reported throughput is then the CRITICAL-PATH rate — touched rows per
-second at wall/N, each shard's own timeline — with ``emulated: true`` and
-``physical_cores`` recorded so a real reading is distinguishable in the
-artifact.  The psum/routing overheads are genuinely paid in-graph either
-way, which is what the weak-scaling gate watches: if cross-shard traffic
-grew past the one-psum-per-step contract, the aggregate rate at N=4 would
-collapse toward 1x.
+second at wall/N, each shard's own timeline if the shards serialize — with
+``emulated: true`` and ``physical_cores`` recorded so a real reading is
+distinguishable in the artifact.
+
+The 4-shard scaling ratio (``scaling_4``) is recorded but not gated: on
+emulated devices it reads the host's core count more than the code.  On
+one core the shards serialize and wall/N is their own timeline (3.18x
+weak, 2.70x strong); on eight cores they run side by side and each step's
+emulated all-reduce costs a thread rendezvous (1.41x/1.28x with one child
+process per mesh size, 1.67x/1.48x with every mesh in one process, same
+machine).  What the gate watches instead is the one-psum-per-step
+contract itself: the compiled round program must hold exactly one
+all-reduce (its scan body runs it once per step), recorded as
+``all_reduces`` per mesh; the run fails if cross-shard traffic grows.
 
 Writes BENCH_dist_linear.json (gated by check_regression.py against
 benchmarks/baselines/); the mesh-size keys are identical in --fast and
@@ -33,6 +41,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import time
 
 import jax
@@ -48,6 +57,15 @@ Q = 16       # weak: features per example PER SHARD (fixed per-shard work)
 DS = 50_000  # weak: rows per shard (fixed per-shard slab)
 P_TOTAL = 64          # strong: features per example, total (divisible by 4)
 STRONG_DIM = 4 * DS   # strong: fixed logical dim
+# collective ops in compiled HLO text (async forms lower to *-start/-done pairs)
+COLLECTIVE = re.compile(
+    r"= \S+ (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(-start)?\("
+)
+
+
+def collectives(compiled) -> list[str]:
+    """Names of the cross-device collectives in a compiled program."""
+    return [m.group(1) for m in COLLECTIVE.finditer(compiled.as_text())]
 
 
 def _measure(mode: str, mesh: int, rounds: int, emulated: bool) -> dict:
@@ -84,6 +102,12 @@ def _measure(mode: str, mesh: int, rounds: int, emulated: bool) -> dict:
     ]
     rrf = dl.make_routed_round_fn(cfg)
     state = lt.init_state(cfg)
+    ops = collectives(rrf.lower(state, *placed[0]).compile())
+    if ops != ["all-reduce"]:
+        raise RuntimeError(
+            f"dist_linear {mode}/n{mesh}: the routed round compiles to collectives "
+            f"{ops}, not the one margin all-reduce per step"
+        )
     state, _ = rrf(state, *placed[0])  # compile + first-touch, untimed
     jax.block_until_ready(state.wpsi)
     t0 = time.perf_counter()
@@ -97,7 +121,7 @@ def _measure(mode: str, mesh: int, rounds: int, emulated: bool) -> dict:
     touched = steps * B * p_tot
     critical = elapsed / n if emulated else elapsed
     return {
-        "dim": dim, "q": q, "steps": steps, "touched_rows": touched,
+        "dim": dim, "q": q, "steps": steps, "touched_rows": touched, "all_reduces": len(ops),
         "wall_s": elapsed, "critical_path_s": critical,
         "touched_rows_per_s": touched / max(critical, 1e-9),
         "us_per_step": 1e6 * critical / steps,
@@ -142,10 +166,10 @@ def run(fast: bool = False, json_path: str = "BENCH_dist_linear.json"):
             ))
         r1 = payload[mode]["1"]["touched_rows_per_s"]
         r4 = payload[mode]["4"]["touched_rows_per_s"]
-        payload[mode]["speedup_4"] = r4 / max(r1, 1e-9)
+        payload[mode]["scaling_4"] = r4 / max(r1, 1e-9)
         rows.append((
-            f"dist_linear/{mode}_speedup", 0.0,
-            f"speedup={payload[mode]['speedup_4']:.2f}x",
+            f"dist_linear/{mode}_scaling", 0.0,
+            f"scaling={payload[mode]['scaling_4']:.2f}x (not gated)",
         ))
     with open(json_path, "w") as f:
         json.dump(payload, f, indent=2, default=float)

@@ -5,8 +5,8 @@ Only performance leaves are gated, direction-aware:
 
   * lower-is-better  (``us_per``, ``_ms``, ``elapsed_s``, ``p50``/``p99``):
     fail when fresh > baseline * (1 + tol)
-  * higher-is-better (``tok_s``, ``speedup``, ``examples_s``, ``_per_s``,
-    ``cfg_steps_s``): fail when fresh < baseline * (1 - tol)
+  * higher-is-better (``tok_s``, ``speedup``, ``examples_s``, ``cfg_steps_s``,
+    or a key ending in ``_per_s``): fail when fresh < baseline * (1 - tol)
 
 Everything else (counters, workload echo, compile counts) is ignored — those
 are asserted by tests, not tolerance-gated.  A gated key present in the
@@ -31,7 +31,9 @@ import sys
 from pathlib import Path
 
 LOWER_IS_BETTER = ("us_per", "_ms", "elapsed_s", "p50", "p99")
-HIGHER_IS_BETTER = ("tok_s", "speedup", "examples_s", "_per_s", "cfg_steps_s")
+HIGHER_IS_BETTER = ("tok_s", "speedup", "examples_s", "cfg_steps_s")
+# a suffix only: ``us_per_step`` is a time, ``touched_rows_per_s`` a rate
+RATE_SUFFIX = "_per_s"
 # single-sample extremes: one scheduler stall on a shared runner moves the
 # max of a run arbitrarily far — informative in the artifact, never gated
 UNGATED = ("max_ms",)
@@ -42,7 +44,7 @@ def direction(key: str):
     named like a time, e.g. tokens_per_elapsed_s, is still a rate)."""
     if any(p in key for p in UNGATED):
         return None
-    if any(p in key for p in HIGHER_IS_BETTER):
+    if any(p in key for p in HIGHER_IS_BETTER) or key.endswith(RATE_SUFFIX):
         return "higher"
     if any(p in key for p in LOWER_IS_BETTER):
         return "lower"

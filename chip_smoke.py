@@ -14,8 +14,8 @@ Phases (one process; each prints one line of what it checked):
   e. hbm       the online service at d = 2^24 (ftrl state: 192 MiB) with
                round flushes over the whole buffer, vs reference
   f. lm        ServeEngine serving stablelm_3b at its published widths
-               (8 requests of 128 + 16 tokens); greedy tokens equal the
-               static lock-step loop's
+               (8 requests of 32 + 16 tokens) vs serve_step.generate on each
+               prompt: equal tokens, or a first difference at a near-tie
 
 Every compared run uses compiled kernels: each phase asserts that its lowered
 program holds a ``tpu_custom_call``.  Any failure raises, and the script exits
@@ -50,17 +50,21 @@ from repro.core import (  # noqa: E402
     nnz,
 )
 from repro.data import BowConfig, SyntheticBow  # noqa: E402
+from repro.configs import get_arch  # noqa: E402
 from repro.launch import compile_cache  # noqa: E402
-from repro.launch.serve import serve_engine, serve_linear  # noqa: E402
+from repro.launch.serve import serve_linear  # noqa: E402
+from repro.models import build, init_params  # noqa: E402
+from repro.serving import EngineConfig, ServeEngine  # noqa: E402
 from repro.sweeps import log_ladder, make_grid  # noqa: E402
+from repro.train import serve_step  # noqa: E402
 
 MEDLINE_DIM = BowConfig().dim  # 260,941
 HASHED_DIM = 2**24
-# online-service rate for requests of ~64 features (p_max 128): at the CLI's
-# 0.3 plain SGD on the hot features diverges, and a 1e-6 perturbation grows
-# to 3e-4 of the weight scale over 384 requests (CPU, reference backend)
-SERVICE_ETA0 = 0.05
 KERNEL = "tpu_custom_call"
+# phase f: how far below the reference's best logit the engine's token may
+# be where the two first differ (bf16 ulps of that logit: the logits are
+# bf16, so one ulp is one rounding)
+TIE_ULPS = 1
 
 
 class SmokeFailure(RuntimeError):
@@ -76,6 +80,11 @@ def require_kernel(lowered, what: str) -> None:
     """The lowered program runs a compiled Pallas kernel: interpret mode and
     the reference backend lower to plain XLA ops, never to this call."""
     check(KERNEL in lowered.as_text(), f"{what}: no {KERNEL} in the lowered program")
+
+
+def bf16_ulp(x):
+    """Spacing of bf16 numbers at ``x`` (8 significant bits)."""
+    return np.exp2(np.floor(np.log2(np.maximum(np.abs(x), 1e-30))) - 7)
 
 
 def report(phase: str, t0: float, msg: str) -> None:
@@ -153,8 +162,7 @@ def phase_service(seed: int, dim: int = MEDLINE_DIM, requests: int = 384,
                   round_len: int = 64, p_max: int = 128, phase: str = "c") -> None:
     """serve_linear (warm-up, then traffic under assert_no_new_compiles) on
     pallas vs the same stream on the reference backend."""
-    stream = dict(dim=dim, p_max=p_max, requests=requests, round_len=round_len, seed=seed,
-                  eta0=SERVICE_ETA0)
+    stream = dict(dim=dim, p_max=p_max, requests=requests, round_len=round_len, seed=seed)
     for solver in ("fobos", "ftrl"):
         t0 = time.monotonic()
         svc, ref = _service_pair(phase, solver, stream, {}, {"backend": "reference"})
@@ -209,46 +217,47 @@ def phase_path(seed: int, dim: int = MEDLINE_DIM, n_rounds: int = 2, round_len: 
     )
 
 
-def static_decode(cfg, model, params, prompts: np.ndarray, new_tokens: int) -> np.ndarray:
-    """The plain lock-step loop (serve_step's prefill and decode steps, as
-    ``serve_static`` runs them) with one change: each prompt is prefilled
-    alone, as the engine admits requests, and the caches are stacked for
-    one batched decode.  Greedy picks between near-tied random-weight
-    logits flip on one bf16 rounding, and a batch-8 prefill multiplies
-    matrices of another shape, which XLA may accumulate in another order
-    (on a v5e it changed 3 of 8 requests).  Returns [n, new_tokens]."""
-    from repro.models import transformer
-    from repro.train.serve_step import make_prefill_step, make_serve_step
+def engine_tokens(model, params, prompts: np.ndarray, new_tokens: int):
+    """The prompts through a ServeEngine with one slot each, after its
+    warm-up, under its zero-recompile assertion.  Returns the tokens
+    [n, new_tokens] and the engine's own decode step, lowered."""
+    n, prompt_len = prompts.shape
+    engine = ServeEngine(model, params, EngineConfig(
+        n_slots=n, max_len=prompt_len + new_tokens, prompt_buckets=(prompt_len,),
+    ))
+    engine.warmup()
+    with engine.compiles.assert_no_new_compiles("lm steady state"):
+        got = np.stack(engine.generate(list(prompts), new_tokens))
+    step = engine._step.lower(
+        params, engine.cache, jnp.asarray(engine._last_tok), jnp.asarray(engine._pos)
+    )
+    return got, step
 
-    prefill = jax.jit(make_prefill_step(cfg, model))
-    step = jax.jit(make_serve_step(cfg, model), donate_argnums=1)
-    firsts, caches = [], []
-    for p in prompts:
-        tok, _, cache = prefill(params, {"tokens": jnp.asarray(p[None], jnp.int32)})
-        firsts.append(tok)
-        caches.append(cache)
-    cache = jax.tree.map(lambda *c: jnp.concatenate(c, axis=1), *caches)  # [L, B, C, ...]
-    pos = prompts.shape[1]
-    cache = transformer.grow_cache(cache, pos + new_tokens)
-    out = [jnp.concatenate(firsts)]
-    for k in range(new_tokens - 1):
-        tok, _, cache = step(params, cache, out[-1], jnp.asarray(pos + k, jnp.int32))
-        out.append(tok)
-    return np.asarray(jnp.stack(out, axis=1))
+
+def reference_tokens(cfg, model, params, prompt: np.ndarray, new_tokens: int):
+    """``serve_step.generate`` on one prompt: tokens [new_tokens] and the
+    logits [new_tokens, V] each was picked from."""
+    logits: list = []
+    out = serve_step.generate(
+        cfg, model, params, {"tokens": jnp.asarray(prompt[None], jnp.int32)}, new_tokens,
+        logits=logits,
+    )
+    return np.asarray(out)[0], np.stack([np.asarray(x[0], np.float32) for x in logits])
 
 
 def phase_lm(seed: int, arch: str = "stablelm_3b", reduced: bool = False, n_req: int = 8,
-             prompt_len: int = 128, new_tokens: int = 16) -> None:
-    """ServeEngine (n_req slots) vs the static lock-step loop at the
-    published widths, both on pallas, on the prompts serve_engine draws.
-    The loops hold KV caches of different capacity (the engine's slots
-    have 16 spare positions), and flash attention streams keys in blocks of
-    min(128, capacity): a 48- and a 64-wide block sum the same scores in
-    different orders.  From a capacity of 128 on both use full 128-wide
-    blocks, masked keys add exact zeros, and the tokens must match."""
-    from repro.configs import get_arch
-    from repro.models import build, init_params
+             prompt_len: int = 32, new_tokens: int = 16) -> None:
+    """ServeEngine (n_req slots) vs ``serve_step.generate`` on each prompt
+    alone, both on pallas, at the published widths with random weights.
 
+    Both prefill one prompt at a time, but the engine decodes a batch of
+    n_req and the reference a batch of 1: the matmuls have other shapes,
+    their sums run in other orders, and a greedy pick between logits tied
+    in bf16 may flip (on a v5e, 4 of 8 requests at seed 0; the engine's
+    larger KV cache changes nothing).  Where a request's tokens first
+    differ, the engine's token must be a tie in the reference's logits:
+    below its best by at most TIE_ULPS bf16 ulps.  The continuations
+    differ from there on and are not compared."""
     t0 = time.monotonic()
     cfg = get_arch(arch)
     if reduced:
@@ -258,22 +267,31 @@ def phase_lm(seed: int, arch: str = "stablelm_3b", reduced: bool = False, n_req:
     gb = sum(x.nbytes for x in jax.tree.leaves(params)) / 1e9
     prompts = np.random.RandomState(seed).randint(0, cfg.vocab_size, size=(n_req, prompt_len))
     with backend.use_backend("pallas"):
-        engine = serve_engine(cfg, model, params, batch=n_req, prompt_len=prompt_len,
-                              new_tokens=new_tokens, seed=seed)
-        static = static_decode(cfg, model, params, prompts, new_tokens)
-        step = jax.jit(model.decode_multi_fn).lower(
-            params, model.cache_spec(n_req, prompt_len + new_tokens),
-            jax.ShapeDtypeStruct((n_req,), jnp.int32), jax.ShapeDtypeStruct((n_req,), jnp.int32),
-        )
+        got, step = engine_tokens(model, params, prompts.astype(np.int32), new_tokens)
+        refs = [reference_tokens(cfg, model, params, p, new_tokens) for p in prompts]
     require_kernel(step, "engine decode step (flash attention)")
-    same = engine == static
-    check(same.all(), f"lm: engine tokens differ from the static loop at {np.argwhere(~same)[:8]}")
+    equal, ties, margins = 0, [], []
+    for r, (ref, logits) in enumerate(refs):
+        top2 = np.sort(logits, axis=-1)[:, -2:]
+        margins.append((top2[:, 1] - top2[:, 0]) / bf16_ulp(top2[:, 1]))
+        diff = np.flatnonzero(got[r] != ref)
+        if diff.size == 0:
+            equal += 1
+            continue
+        j = int(diff[0])
+        gap = float(logits[j, ref[j]] - logits[j, got[r, j]]) / float(bf16_ulp(logits[j, ref[j]]))
+        check(gap <= TIE_ULPS, f"lm: request {r} step {j}: engine token {got[r, j]} is "
+              f"{gap:.1f} bf16 ulps below the reference's best (> {TIE_ULPS})")
+        ties.append(f"r{r}@{j}:{gap:.2f}")
     report(
         "f", t0,
         f"lm: {cfg.name} L={cfg.n_layers} d_model={cfg.d_model} vocab={cfg.vocab_size} "
         f"params={gb:.2f} GB {jnp.dtype(jax.tree.leaves(params)[0].dtype).name}; "
-        f"{n_req} reqs x ({prompt_len}+{new_tokens}) over {n_req} slots: engine tokens == "
-        f"static tokens ({engine.size} of {engine.size}); flash kernel in the decode step",
+        f"{n_req} reqs x ({prompt_len}+{new_tokens}) over {n_req} slots vs generate per prompt: "
+        f"{equal} of {n_req} equal token for token; first differences at near-ties "
+        f"[{', '.join(ties)}] bf16 ulps (<= {TIE_ULPS}); median top-2 margin "
+        f"{float(np.median(np.concatenate(margins))):.1f} ulps; flash kernel in the engine's "
+        f"decode step; zero recompiles after warm-up",
     )
 
 
@@ -281,8 +299,7 @@ def phase_sharded(seed: int, dim: int = HASHED_DIM, requests: int = 256,
                   round_len: int = 32, p_max: int = 128, chips: int = 4) -> None:
     """LinearService feature-sharded over ``chips`` devices vs mesh=1 on the
     same stream (pallas both); the state must sit on every device."""
-    stream = dict(dim=dim, p_max=p_max, requests=requests, round_len=round_len, seed=seed,
-                  eta0=SERVICE_ETA0)
+    stream = dict(dim=dim, p_max=p_max, requests=requests, round_len=round_len, seed=seed)
     for solver in ("fobos", "ftrl"):
         t0 = time.monotonic()
         svc, one = _service_pair(

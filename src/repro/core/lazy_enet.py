@@ -25,6 +25,23 @@ import jax.numpy as jnp
 from .dp_caches import RegCaches, concrete_zero
 
 
+# below this, 1 + expm1 drifts past 1 ulp (its add rounds a second time)
+EXPM1_FLOOR = -1.0 / 16
+
+
+def exp_nonpos(x: jnp.ndarray) -> jnp.ndarray:
+    """``exp(x)`` for ``x <= 0`` (a decay): ``1 + expm1(x)`` above
+    ``EXPM1_FLOOR``, plain ``exp(x)`` below.
+
+    A short window's decay is tiny (~eta*lam2 per step), and the TPU's f32
+    exp of such arguments reads low (18 ulp on average on v5e, 3.1 ulp at
+    most for 1 + expm1): a bias every touch of a hot feature repeats.  A
+    window that decays a weight by more than 6% is rare for any one weight,
+    so exp's bias there cannot pile up.  On the CPU both branches read
+    within 1 ulp (tests/core/test_dp_caches.py)."""
+    return jnp.where(x > EXPM1_FLOOR, 1.0 + jnp.expm1(x), jnp.exp(x))
+
+
 def catchup_factors(psi: jnp.ndarray, k: jnp.ndarray, caches: RegCaches, lam1):
     """Per-entry multiplicative ``ratio`` and subtractive ``shift`` such that
     the lazy update is ``sgn(w) * relu(|w| * ratio - shift)``.
@@ -32,22 +49,18 @@ def catchup_factors(psi: jnp.ndarray, k: jnp.ndarray, caches: RegCaches, lam1):
       ratio = exp(logP[k] - logP[psi])                (window product of a's)
       shift = lam1 * exp(logP[k]) * (B[k] - B[psi])   (collapsed lam1 shifts)
 
-    ``lam1`` may be a traced scalar (per-config, under vmap); only a
-    concrete 0 takes the no-l1 shortcut.
+    both exps through :func:`exp_nonpos`.  ``lam1`` may be a traced scalar
+    (per-config, under vmap); only a concrete 0 takes the no-l1 shortcut.
     """
     logP_k = caches.logP[k]
     logP_psi = caches.logP[psi]
-    # 1 + expm1, not exp: a short window's decay is tiny (~eta*lam2 per
-    # step), and the TPU's f32 exp of such arguments reads ~1e-6 low (18
-    # ulp on average on v5e) — a bias every touch of a hot feature repeats.
-    # expm1 keeps the decay exact to ~1e-7 there.
-    ratio = 1.0 + jnp.expm1(logP_k - logP_psi)
+    ratio = exp_nonpos(logP_k - logP_psi)
     if concrete_zero(lam1):
         shift = jnp.zeros_like(ratio)
     else:
         # Computed as exp(logP[k]) * (B[k]-B[psi]): with round-rebased caches
         # |logP| stays O(1) so there is no under/overflow (DESIGN.md §2).
-        shift = lam1 * jnp.exp(logP_k) * (caches.B[k] - caches.B[psi])
+        shift = lam1 * exp_nonpos(logP_k) * (caches.B[k] - caches.B[psi])
     return ratio, shift
 
 

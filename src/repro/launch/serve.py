@@ -119,15 +119,21 @@ def serve_engine(cfg, model, params, *, batch, prompt_len, new_tokens, seed=0,
     return np.stack([f.result(timeout=0) for f in futs], axis=0)
 
 
+def service_eta0(p_max: int) -> float:
+    """The smokes' base rate: 0.3 for requests of ``p_max / 2 = 16``
+    features, then inversely with the features per request.  At a fixed
+    0.3, requests of ~64 features make plain SGD on the hot features
+    chaotic: a 1e-6 change of the rate moves the weights by 3e-4 of their
+    scale over 384 requests, against ~1e-6 at this rule's 0.075."""
+    return 0.3 * min(1.0, 32 / p_max)
+
+
 def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch=8,
                  requests=256, round_len=256, seed=0, fused=None, state_dtype="f32",
-                 mesh=None, eta0=0.3):
+                 mesh=None):
     """Online learn/predict smoke over the LinearService: warm the complete
     jit set (every power-of-two bucket x {learn, predict} + the round
     flush), then stream ``requests`` examples and assert zero recompiles.
-    Requests carry ``p_max / 2`` features on average; ``eta0`` = 0.3 suits
-    the default 16, while ~64 (``p_max=128``) needs a smaller rate, or
-    plain SGD on the hot features diverges.
 
     ``mesh=N`` feature-shards the packed solver state across N devices
     (repro.dist.linear); the same zero-recompile assertion holds — routing
@@ -138,7 +144,7 @@ def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch
 
     cfg = LinearConfig(
         dim=dim, round_len=round_len, lam1=1e-5, lam2=1e-6,
-        schedule=ScheduleConfig(kind="inv_sqrt", eta0=eta0, t0=100.0),
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=service_eta0(p_max), t0=100.0),
         fused=fused, state_dtype=state_dtype, mesh=mesh,
     )
     svc = LinearService(cfg, ServiceConfig(
@@ -211,7 +217,7 @@ def serve_multitenant(*, tenants=8, solver=None, backend=None, dim=20_000,
 
     cfg = LinearConfig(
         dim=dim, round_len=round_len, lam1=1e-5, lam2=1e-6,
-        schedule=ScheduleConfig(kind="inv_sqrt", eta0=0.3, t0=100.0),
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=service_eta0(p_max), t0=100.0),
         fused=fused, state_dtype=state_dtype, mesh=mesh,
     )
     svc = MultiLinearService(cfg, n_slots=tenants, service=ServiceConfig(

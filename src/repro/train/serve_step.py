@@ -4,7 +4,7 @@ cells lower (one new token against a seq_len-deep cache)."""
 from __future__ import annotations
 
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,12 +37,15 @@ def make_serve_step(cfg: ArchConfig, model: ModelFns, *, temperature: float = 0.
 
 def generate(cfg: ArchConfig, model: ModelFns, params, batch, n_new: int,
              *, temperature: float = 0.0, seed: int = 0,
-             timings: Optional[Dict[str, float]] = None):
+             timings: Optional[Dict[str, float]] = None,
+             logits: Optional[List[jax.Array]] = None):
     """Convenience loop (examples / tests / the engine's parity baseline):
     prefill then decode n_new tokens — greedy, or sampled with a
     split-per-step key when temperature > 0.  Python loop — fine at example
     scale.  Pass a dict as ``timings`` to receive block_until_ready-accurate
-    "prefill_s" / "decode_s" (launch/serve.py's static driver reads them)."""
+    "prefill_s" / "decode_s" (launch/serve.py's static driver reads them),
+    and a list as ``logits`` to receive the [B, V] logits each token was
+    picked from."""
     prefill = jax.jit(make_prefill_step(cfg, model))
     step = jax.jit(make_serve_step(cfg, model, temperature=temperature), donate_argnums=1)
     t0 = time.monotonic()
@@ -60,13 +63,17 @@ def generate(cfg: ArchConfig, model: ModelFns, params, batch, n_new: int,
         key, sub = jax.random.split(key)
         tok = jax.random.categorical(sub, last_logits / temperature, axis=-1).astype(jnp.int32)
     out = [tok]
+    if logits is not None:
+        logits.append(last_logits)
     t0 = time.monotonic()
     for k in range(n_new - 1):
         sub = None
         if key is not None:
             key, sub = jax.random.split(key)
-        tok, _, cache = step(params, cache, tok, jnp.asarray(pos + k, jnp.int32), sub)
+        tok, step_logits, cache = step(params, cache, tok, jnp.asarray(pos + k, jnp.int32), sub)
         out.append(tok)
+        if logits is not None:
+            logits.append(step_logits)
     if timings is not None:
         jax.block_until_ready(tok)
         timings["decode_s"] = time.monotonic() - t0

@@ -10,7 +10,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
-from repro._testing import assert_ulp_close
+from repro._testing import assert_f32_close
 from repro.core import (
     FOBOS,
     SGD,
@@ -144,9 +144,9 @@ def test_reference_backend_keeps_sweep_bitwise(seed, flavor, lam1, lam2, kind):
     state, losses = round_fn(init_state(grid.config_at(0)), rounds[0])
 
     # the batched and plain programs fuse differently under this XLA
-    assert_ulp_close(np.asarray(bstate.wpsi[0]), np.asarray(state.wpsi))
-    assert_ulp_close(np.asarray(bstate.b)[0], np.asarray(state.b))
-    assert_ulp_close(blosses[0], np.asarray(losses))
+    assert_f32_close(np.asarray(bstate.wpsi[0]), np.asarray(state.wpsi))
+    np.testing.assert_array_max_ulp(np.asarray(bstate.b)[0], np.asarray(state.b), maxulp=2)
+    np.testing.assert_array_max_ulp(blosses[0], np.asarray(losses), maxulp=2)
 
 
 def test_vmapped_sweep_runs_on_pallas(rng):

@@ -1,9 +1,11 @@
 """The DP caches must reproduce the direct (non-DP) window products / sums."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import FOBOS, SGD, extend, init_caches, log_a
+from repro.core.lazy_enet import EXPM1_FLOOR, catchup_factors
 
 
 def _build(etas, lam2, flavor):
@@ -61,3 +63,39 @@ def test_log_a_flavors():
     np.testing.assert_allclose(float(log_a(eta, 0.2, SGD)), np.log(0.9), rtol=1e-6)
     np.testing.assert_allclose(float(log_a(eta, 0.2, FOBOS)), -np.log(1.1), rtol=1e-6)
     assert float(log_a(eta, 0.0, SGD)) == 0.0
+
+
+@pytest.mark.parametrize("flavor", [SGD, FOBOS])
+@pytest.mark.parametrize("lam2", [1e-4, 1e-2])  # windows that decay < 6%; and to 5%
+def test_catchup_factors_match_float64(flavor, lam2):
+    """ratio within 1 ulp and shift (three rounded factors) within 2 ulps of
+    float64 on the same f32 caches.  Measured on the CPU: 0.85 and 1.97."""
+    R, lam1 = 1024, np.float32(2e-4)
+    etas = jnp.asarray(0.5 / np.sqrt(1.0 + np.arange(R) / 200.0), jnp.float32)
+    caches = jax.jit(
+        lambda: jax.lax.fori_loop(
+            0, R, lambda i, c: extend(c, i, etas[i], lam2, flavor), init_caches(R)
+        )
+    )()
+    rng = np.random.default_rng(0)
+    ends = rng.integers(0, R + 1, size=(2, 4000))
+    psi, k = ends.min(axis=0), ends.max(axis=0)
+    ratio, shift = catchup_factors(jnp.asarray(psi), jnp.asarray(k), caches, float(lam1))
+
+    logP, B = np.asarray(caches.logP), np.asarray(caches.B)
+    x = (logP[k] - logP[psi]).astype(np.float64)  # the f32 difference the code takes
+    assert (x < EXPM1_FLOOR).any() == (lam2 > 1e-3)  # both branches of exp_nonpos
+    ratio64 = np.exp(x)
+    shift64 = np.float64(lam1) * np.exp(logP[k].astype(np.float64)) * (B[k] - B[psi])
+    np.testing.assert_array_max_ulp(np.asarray(ratio), ratio64.astype(np.float32), maxulp=1)
+    np.testing.assert_array_max_ulp(np.asarray(shift), shift64.astype(np.float32), maxulp=2)
+
+
+def test_catchup_ratio_reads_short_windows_through_expm1():
+    """The TPU's f32 exp of small arguments reads ~18 ulp low; the CPU's
+    does not, so only the program's structure can pin the choice here."""
+    caches = init_caches(4)
+    jaxpr = str(jax.make_jaxpr(catchup_factors, static_argnums=3)(
+        jnp.zeros(3, jnp.int32), jnp.ones(3, jnp.int32), caches, 1e-3
+    ))
+    assert jaxpr.count("expm1") == 2  # ratio and shift

@@ -14,7 +14,7 @@ import numpy as np
 from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
-from repro._testing import assert_ulp_close
+from repro._testing import assert_f32_close
 from repro.core import (
     FOBOS,
     SGD,
@@ -91,9 +91,9 @@ def test_batch_of_one_bitwise_equals_plain_fit(seed, flavor, lam1, lam2, eta0, k
     losses = np.concatenate(losses)
 
     # the batched and plain programs fuse differently under this XLA
-    assert_ulp_close(np.asarray(bstate.wpsi[0]), np.asarray(state.wpsi))
-    assert_ulp_close(np.asarray(bstate.b)[0], np.asarray(state.b))
-    assert_ulp_close(blosses[0], losses)
+    assert_f32_close(np.asarray(bstate.wpsi[0]), np.asarray(state.wpsi))
+    np.testing.assert_array_max_ulp(np.asarray(bstate.b)[0], np.asarray(state.b), maxulp=2)
+    np.testing.assert_array_max_ulp(blosses[0], losses, maxulp=2)
 
 
 def test_grid_matches_sequential_fits():

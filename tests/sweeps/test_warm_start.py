@@ -5,7 +5,7 @@ inside the previous optimum's basin)."""
 
 import numpy as np
 
-from repro._testing import assert_ulp_close
+from repro._testing import assert_f32_close
 from repro.core import LinearConfig, ScheduleConfig
 from repro.data import BowConfig, SyntheticBow
 from repro.sweeps import log_ladder, make_grid, run_grid, run_path
@@ -48,9 +48,10 @@ def test_cold_path_equals_grid_fit():
     cold = run_path(grid, rounds, warm_start=False)
     bstate, losses = run_grid(grid, rounds)
     # the batched and plain programs fuse differently under this XLA
-    assert_ulp_close(cold.weights, np.asarray(bstate.wpsi[:, :, 0]))
-    assert_ulp_close(cold.b, np.asarray(bstate.b))
-    assert_ulp_close(cold.losses, losses)
+    # (here bias and losses too: 3 and 4 ulps)
+    assert_f32_close(cold.weights, np.asarray(bstate.wpsi[:, :, 0]))
+    assert_f32_close(cold.b, np.asarray(bstate.b))
+    assert_f32_close(cold.losses, losses)
 
 
 def test_warm_start_beats_cold_start_on_lam1_path():

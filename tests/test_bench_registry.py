@@ -7,8 +7,11 @@ and every registered suite lazily imports a module that exists.
 Source-level checks only (no jax, no bench execution): the registry's
 runners reference their modules via ``_m("bench_<stem>")`` literals.
 """
+import importlib.util
 import re
 from pathlib import Path
+
+import pytest
 
 BENCH_DIR = Path(__file__).resolve().parents[1] / "benchmarks"
 
@@ -41,3 +44,28 @@ def test_suite_names_cover_json_baselines():
     sources = "".join(p.read_text() for p in BENCH_DIR.glob("bench_*.py"))
     orphans = [b.name for b in baselines if b.name not in sources]
     assert not orphans, f"baselines with no producing bench module: {orphans}"
+
+
+def _check_regression():
+    spec = importlib.util.spec_from_file_location(
+        "check_regression", BENCH_DIR / "check_regression.py"
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize(
+    "key, sense",
+    [
+        ("us_per_step", "lower"),  # a time, though it holds "_per_s"
+        ("p99_ms", "lower"),
+        ("touched_rows_per_s", "higher"),
+        ("screen_speedup", "higher"),
+        ("scaling_4", None),  # emulated-mesh scaling is recorded, not gated
+        ("all_reduces", None),
+        ("max_ms", None),
+    ],
+)
+def test_gate_direction(key, sense):
+    assert _check_regression().direction(key) == sense
