@@ -39,7 +39,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import dp_caches
+from . import dp_caches, phases
 from .dp_caches import FLAVORS, RegCaches
 from .schedules import ScheduleConfig
 
@@ -354,7 +354,8 @@ def flush(cfg: LinearConfig, state: LinearState, lam1=None, hp: Optional[Hypers]
         hp = cfg.hypers(lam1=lam1)
     if cfg.mesh is not None:
         return _dist().flush(cfg, state, hp=hp)  # shard-local, no collectives
-    return _solver(cfg).flush(cfg, state, hp, _backend(cfg.backend))
+    with jax.named_scope(phases.FLUSH):
+        return _solver(cfg).flush(cfg, state, hp, _backend(cfg.backend))
 
 
 def current_weights(

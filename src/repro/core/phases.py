@@ -1,0 +1,23 @@
+"""Names of the lazy step's phases, each a ``jax.named_scope`` around the
+code that does it.  A scope changes only the compiled program's metadata
+(every op's ``op_name`` carries it, e.g. ``.../while/body/lazy.scatter/
+scatter``), never its ops, so a profile of the round program can be split
+by phase without changing what runs.
+
+* ``GATHER``: everything read before the update — the touched rows'
+  gather, and for the cache-based solvers the DP-cache extend and the
+  catch-up factors;
+* ``KERNEL``: the update itself — the backend's fused whole-step op, or the
+  unfused chain (catch-up or apply-at-read, predict, gradient), and on a
+  feature mesh the margin psum;
+* ``SCATTER``: the write-back — the scatter-SET/ADD into the state, the
+  storage-grid round-trips, the bias step;
+* ``FLUSH``: the round flush (``core.linear_trainer.flush``,
+  ``dist.linear.local_flush``).
+"""
+
+GATHER = "lazy.gather"
+KERNEL = "lazy.kernel"
+SCATTER = "lazy.scatter"
+FLUSH = "lazy.flush"
+PHASES = (GATHER, KERNEL, SCATTER, FLUSH)

@@ -53,7 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import dp_caches
+from repro.core import dp_caches, phases
 from repro.core import linear_trainer as lt
 from repro.core.dp_caches import RegCaches
 from repro.core.linear_trainer import Hypers, LinearState, SparseBatch
@@ -180,7 +180,8 @@ def make_local_step_hp(cfg):
 def local_flush(cfg, state: LinearState, hp: Hypers) -> LinearState:
     """Shard-local flush: the caches/clock are replicated, so every shard
     rebases identically while bringing only its own rows current."""
-    return lt._solver(cfg).flush(cfg, state, hp, lt._backend(cfg.backend))
+    with jax.named_scope(phases.FLUSH):
+        return lt._solver(cfg).flush(cfg, state, hp, lt._backend(cfg.backend))
 
 
 def _local_predict(cfg, solver, state: LinearState, batch: SparseBatch, hp: Hypers):

@@ -51,5 +51,6 @@ def enet_prox_kernel(
         ],
         out_specs=tile_spec(block_rows, block_cols),
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+        name="enet_prox",
         interpret=interpret,
     )(w, *dynamic_hypers(a, s))

@@ -249,6 +249,7 @@ def _fwd_impl(q, k, v, q_offset, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((B * H, Sq_p, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn_fwd",
     )(offs, q2, k2, v2)
 
     return out.reshape(B, H, Sq_p, hd)[:, :, :Sq], lse
@@ -298,6 +299,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
         out_specs=pl.BlockSpec((1, block_q, hd), qmap),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, hd), q.dtype),
         interpret=interpret,
+        name="flash_attn_dq",
     )(offs, q2, k2, v2, do2, lse, delta2)
 
     kv_tile = lambda bh, nk, KV=KV, G=G: ((bh // (G * KV)) * KV + (bh % (G * KV)) // G, nk, 0)
@@ -322,6 +324,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, do):
             jax.ShapeDtypeStruct((B * H, Skv_p, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn_dkv",
     )(offs, q2, k2, v2, do2, lse, delta2)
 
     dq = dq.reshape(B, H, Sq_p, hd)[:, :, :Sq]

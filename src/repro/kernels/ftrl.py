@@ -83,6 +83,7 @@ def ftrl_read_rows_kernel(
         in_specs=[tile_spec(block_rows, block_cols)] * 2 + [SCALAR_SPEC] * 4,
         out_specs=tile_spec(block_rows, block_cols),
         out_shape=jax.ShapeDtypeStruct(z.shape, jnp.float32),
+        name="ftrl_read_rows",
         interpret=interpret,
     )(z, n, *dynamic_hypers(alpha, beta, lam1, lam2))
 
@@ -112,5 +113,6 @@ def ftrl_update_rows_kernel(
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
         ),
+        name="ftrl_update_rows",
         interpret=interpret,
     )(w, n, g, *dynamic_hypers(alpha))

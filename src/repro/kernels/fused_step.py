@@ -161,6 +161,7 @@ def dp_fused_step_kernel(
             jax.ShapeDtypeStruct((B, 1), f32),
             jax.ShapeDtypeStruct((B, 1), f32),
         ),
+        name="dp_fused_step",
         interpret=interpret,
     )(w, ratio, shift, val, y, *dynamic_hypers(b, eta))
 
@@ -202,5 +203,6 @@ def ftrl_fused_step_kernel(
             jax.ShapeDtypeStruct((B, 1), f32),
             jax.ShapeDtypeStruct((B, 1), f32),
         ),
+        name="ftrl_fused_step",
         interpret=interpret,
     )(z, n, val, y, *dynamic_hypers(b, alpha, beta, lam1, lam2))

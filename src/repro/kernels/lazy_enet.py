@@ -101,6 +101,7 @@ def lazy_enet_rows_kernel(
         ],
         out_specs=tile_spec(block_rows, block_cols),
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+        name="lazy_enet_rows",
         interpret=interpret,
     )(w, grad, ratio, shift, *dynamic_hypers(eta))
 
@@ -133,5 +134,6 @@ def enet_apply_rows_kernel(
         ],
         out_specs=tile_spec(block_rows, block_cols),
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+        name="enet_apply_rows",
         interpret=interpret,
     )(w, ratio, shift)

@@ -82,6 +82,7 @@ def dp_margin_rows_kernel(
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
         ),
+        name="dp_margin_rows",
         interpret=interpret,
     )(w, ratio, shift, val)
 
@@ -114,5 +115,6 @@ def ftrl_margin_rows_kernel(
             jax.ShapeDtypeStruct(z.shape, jnp.float32),
             jax.ShapeDtypeStruct(z.shape, jnp.float32),
         ),
+        name="ftrl_margin_rows",
         interpret=interpret,
     )(z, n, val, *dynamic_hypers(alpha, beta, lam1, lam2))

@@ -67,5 +67,6 @@ def screen_rows_kernel(
             jax.ShapeDtypeStruct(g.shape, jnp.float32),
             jax.ShapeDtypeStruct(g.shape, jnp.float32),
         ),
+        name="screen_rows",
         interpret=interpret,
     )(g, w, *dynamic_hypers(thr, chk))

@@ -48,7 +48,6 @@ from .sinks import (
     run_logger,
 )
 from .trace import profile_to, span, step_annotation
-from .events import tap
 
 __all__ = [
     "CompileTracker",
@@ -79,5 +78,4 @@ __all__ = [
     "profile_to",
     "span",
     "step_annotation",
-    "tap",
 ]

@@ -26,7 +26,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import events, metrics_state
+from . import metrics_state
 from .metrics_state import MetricsState, init_metrics
 
 
@@ -78,12 +78,10 @@ def init_obs(cfg, w0=None) -> Tuple[object, MetricsState]:
     return lt.init_state(cfg, w0), init_metrics()
 
 
-def make_obs_round_fn(cfg, event_tap: bool = False):
+def make_obs_round_fn(cfg):
     """Instrumented twin of ``core.make_round_fn(cfg, "lazy")``: scans a
     round over the ``(LinearState, MetricsState)`` carry, flushes at the
-    boundary, and records the flush + post-flush weight nnz.  With
-    ``event_tap`` the flush also fires an io_callback event to the active
-    RunLogger (rare — once per round), carrying the live step/nnz scalars."""
+    boundary, and records the flush + post-flush weight nnz."""
     from repro.core import linear_trainer as lt
 
     step = make_obs_step(cfg)
@@ -96,16 +94,6 @@ def make_obs_round_fn(cfg, event_tap: bool = False):
         # post-flush, column 0 is current for every solver (cache-based
         # solvers rebase; apply-at-read solvers rematerialize w)
         m = metrics_state.record_flush(m, state.wpsi[:, 0])
-        if event_tap:
-            events.tap(
-                "flush",
-                {
-                    "step": state.t,
-                    "flushes": m.flushes,
-                    "nnz": m.nnz,
-                    "touched_coords": m.touched,
-                },
-            )
         return (state, m), losses
 
     return round_fn

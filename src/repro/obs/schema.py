@@ -7,7 +7,7 @@ adds; per-kind required fields:
 
   run_meta  {kind, ts, t, program, meta}        + optional d (int)
   metrics   {kind, ts, t, data}                 + optional step (int)
-  span      {kind, ts, t, name, dur_s, attrs}
+  span      {kind, ts, t, name, dur_s, attrs}    + optional start_ns (int)
   event     {kind, ts, t, name, data}
 
 ``data``/``meta``/``attrs`` are open objects (forward-compatible: readers
@@ -54,6 +54,9 @@ def validate_event(obj: object, lineno: int = 0) -> List[str]:
     if kind == "metrics" and "step" in obj:
         if not isinstance(obj["step"], int) or isinstance(obj["step"], bool):
             errors.append(f"{where}metrics.step must be an int")
+    if kind == "span" and "start_ns" in obj:
+        if not isinstance(obj["start_ns"], int) or isinstance(obj["start_ns"], bool):
+            errors.append(f"{where}span.start_ns must be an int")
     return errors
 
 

@@ -85,9 +85,13 @@ class RunLogger:
             payload["step"] = int(step)
         self._emit("metrics", payload)
 
-    def span(self, name: str, dur_s: float, **attrs) -> None:
-        """A completed tracing span (obs.trace.span emits these)."""
-        self._emit("span", {"name": name, "dur_s": float(dur_s), "attrs": attrs})
+    def span(self, name: str, dur_s: float, start_ns: Optional[int] = None, **attrs) -> None:
+        """A completed tracing span (obs.trace.span emits these);
+        ``start_ns`` on the profiler's clock (see obs.trace.span)."""
+        payload: Dict[str, object] = {"name": name, "dur_s": float(dur_s), "attrs": attrs}
+        if start_ns is not None:
+            payload["start_ns"] = int(start_ns)
+        self._emit("span", payload)
 
     def event(self, name: str, **data) -> None:
         """A rare point event (flush, round boundary, weight swap, ...)."""

@@ -11,7 +11,15 @@ profile), and — when a RunLogger is active (:mod:`repro.obs.sinks`) —
 emits a ``span`` JSONL event carrying the duration, the caller's
 attributes, and, if a tracker was given, the jit-cache delta across the
 span (compiles attributable to this phase).  With no active logger the
-cost is two clock reads.
+cost is three clock reads.
+
+The event's ``start_ns`` is read from the clock the profiler stamps its
+host events with (``time.time_ns()``, the wall clock), just before the
+annotation opens.  A collected profile stores its events relative to the
+``profile_start_time`` of its "Task Environment" plane, on that same
+clock, so a span in a ``--log`` file lines up with its annotation in a
+``--profile`` trace of the same run: annotation start = start_ns -
+profile_start_time.
 
 ``profile_to(dir)`` wraps ``jax.profiler.start_trace/stop_trace`` for the
 launch CLIs' ``--profile DIR`` flag; ``step_annotation(i)`` is the
@@ -36,6 +44,7 @@ def span(name: str, tracker: Optional[CompileTracker] = None, **attrs):
     without one).  ``tracker`` adds the compile-cache delta across the
     span to the event (which functions compiled, and how many entries)."""
     before = tracker.counts() if tracker is not None else None
+    start_ns = time.time_ns()  # the profiler's clock
     t0 = time.monotonic()
     with jax.profiler.TraceAnnotation(name):
         yield
@@ -46,7 +55,7 @@ def span(name: str, tracker: Optional[CompileTracker] = None, **attrs):
             after = tracker.counts()
             delta = {k: after[k] - before.get(k, 0) for k in after}
             attrs = {**attrs, "compiles": delta}
-        logger.span(name, dur, **attrs)
+        logger.span(name, dur, start_ns=start_ns, **attrs)
 
 
 def step_annotation(step: int):

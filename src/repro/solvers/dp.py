@@ -20,9 +20,10 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
-from repro.core import dp_caches, lazy_enet, state_compress
+from repro.core import dp_caches, lazy_enet, phases, state_compress
 from repro.core.dp_caches import FOBOS, SGD
 from repro.core.schedules import validate_schedule
 
@@ -65,54 +66,60 @@ class LazyCacheSolver(Solver):
     def touched_update(self, cfg, state, batch, hp, eta, bk) -> Tuple[object, jnp.ndarray]:
         from repro.core import linear_trainer as lt
 
-        # O(1): fill DP cache slot i+1 with this step's eta (Lemma 1 / Thm 1-2)
-        caches = self.extend_caches(
-            state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
-        )
-        idx_f = batch.idx.reshape(-1)
-        # --- single gather: (w, psi) rows for the touched features ---
-        g2 = state.wpsi[idx_f]  # [B*p, 2]
-        w_g = g2[:, 0]
-        psi_g = g2[:, 1].astype(jnp.int32)
-        shape = batch.idx.shape
-        if lt.fused_enabled(cfg):
-            # (ratio, shift) from the caches in XLA — tiny O(B*p) gathers +
-            # exps, and where a traced per-config lam1 enters — then ONE
-            # whole-step tile pass: catch-up, predict, gradient, update delta
-            ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
-            w_cur2, delta, gz, loss = bk.fused_step(
-                w_g.reshape(shape),
-                ratio.reshape(shape),
-                jnp.broadcast_to(shift, ratio.shape).reshape(shape),
-                batch.val,
-                batch.y,
-                state.b,
-                eta,
-                loss=cfg.loss,
-                use_bias=cfg.use_bias,
+        fused = lt.fused_enabled(cfg)
+        with jax.named_scope(phases.GATHER):
+            # O(1): fill DP cache slot i+1 with this step's eta (Lemma 1 / Thm 1-2)
+            caches = self.extend_caches(
+                state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
             )
-            w_cur = w_cur2.reshape(-1)
-            neg_eta_g = delta.reshape(-1)  # [B*p]
-        else:
-            # --- lazy catch-up of touched weights: reg for tau in [psi, i) ---
-            w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
-            # --- predict with current weights, loss gradient ---
-            z = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
-            loss, gz = lt._grad_z(cfg, z, batch.y)
-            neg_eta_g = -eta * (gz[:, None] * batch.val).reshape(-1)  # [B*p]
-        # --- write back: set (caught-up w, psi=i) — duplicates identical —
-        # then scatter-ADD the loss-gradient step (duplicates accumulate) ---
-        # psi round-trips its storage grid on write (exact by validate();
-        # the f32 default is the identity)
-        psi_new = state_compress.roundtrip(
-            jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
-            cfg.state_dtype,
-            integer=True,
-        )
-        upd = jnp.stack([w_cur, psi_new], axis=1)
-        wpsi = state.wpsi.at[idx_f].set(upd)
-        wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
-        b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
+            idx_f = batch.idx.reshape(-1)
+            # --- single gather: (w, psi) rows for the touched features ---
+            g2 = state.wpsi[idx_f]  # [B*p, 2]
+            w_g = g2[:, 0]
+            psi_g = g2[:, 1].astype(jnp.int32)
+            shape = batch.idx.shape
+            if fused:
+                # (ratio, shift) from the caches in XLA — tiny O(B*p) gathers
+                # + exps, and where a traced per-config lam1 enters
+                ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
+        with jax.named_scope(phases.KERNEL):
+            if fused:
+                # ONE whole-step tile pass: catch-up, predict, gradient,
+                # update delta
+                w_cur2, delta, gz, loss = bk.fused_step(
+                    w_g.reshape(shape),
+                    ratio.reshape(shape),
+                    jnp.broadcast_to(shift, ratio.shape).reshape(shape),
+                    batch.val,
+                    batch.y,
+                    state.b,
+                    eta,
+                    loss=cfg.loss,
+                    use_bias=cfg.use_bias,
+                )
+                w_cur = w_cur2.reshape(-1)
+                neg_eta_g = delta.reshape(-1)  # [B*p]
+            else:
+                # --- lazy catch-up of touched weights: reg for tau in [psi, i) ---
+                w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
+                # --- predict with current weights, loss gradient ---
+                z = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
+                loss, gz = lt._grad_z(cfg, z, batch.y)
+                neg_eta_g = -eta * (gz[:, None] * batch.val).reshape(-1)  # [B*p]
+        with jax.named_scope(phases.SCATTER):
+            # --- write back: set (caught-up w, psi=i) — duplicates identical —
+            # then scatter-ADD the loss-gradient step (duplicates accumulate) ---
+            # psi round-trips its storage grid on write (exact by validate();
+            # the f32 default is the identity)
+            psi_new = state_compress.roundtrip(
+                jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
+                cfg.state_dtype,
+                integer=True,
+            )
+            upd = jnp.stack([w_cur, psi_new], axis=1)
+            wpsi = state.wpsi.at[idx_f].set(upd)
+            wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
+            b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         # reg for step i itself stays pending (applied at next touch / flush)
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
@@ -126,42 +133,47 @@ class LazyCacheSolver(Solver):
         from repro.core import linear_trainer as lt
         from repro.dist import linear as dl
 
-        caches = self.extend_caches(
-            state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
-        )
-        idx_f = batch.idx.reshape(-1)
-        g2 = state.wpsi[idx_f]  # [B*p, 2] clip-gather; sentinel rows masked
-        w_g = g2[:, 0]
-        psi_g = g2[:, 1].astype(jnp.int32)
-        shape = batch.idx.shape
-        if lt.fused_enabled(cfg):
-            ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
-            # shard-local fused pass: catch-up + masked margin contributions
-            w_cur2, contrib = bk.fused_margin(
-                w_g.reshape(shape),
-                ratio.reshape(shape),
-                jnp.broadcast_to(shift, ratio.shape).reshape(shape),
-                batch.val,
+        fused = lt.fused_enabled(cfg)
+        with jax.named_scope(phases.GATHER):
+            caches = self.extend_caches(
+                state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
             )
-            w_cur = w_cur2.reshape(-1)
-        else:
-            w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
-            contrib = w_cur.reshape(shape) * batch.val
-        # --- the ONLY cross-shard traffic: the per-example margin ---
-        z = dl.margin_psum(cfg, contrib)
-        if cfg.use_bias:
-            z = z + state.b
-        loss, gz = lt._grad_z(cfg, z, batch.y)
-        neg_eta_g = (-eta * (gz[:, None] * batch.val)).reshape(-1)  # [B*p]
-        psi_new = state_compress.roundtrip(
-            jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
-            cfg.state_dtype,
-            integer=True,
-        )
-        upd = jnp.stack([w_cur, psi_new], axis=1)
-        wpsi = state.wpsi.at[idx_f].set(upd)
-        wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
-        b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
+            idx_f = batch.idx.reshape(-1)
+            g2 = state.wpsi[idx_f]  # [B*p, 2] clip-gather; sentinel rows masked
+            w_g = g2[:, 0]
+            psi_g = g2[:, 1].astype(jnp.int32)
+            shape = batch.idx.shape
+            if fused:
+                ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
+        with jax.named_scope(phases.KERNEL):
+            if fused:
+                # shard-local fused pass: catch-up + masked margin contributions
+                w_cur2, contrib = bk.fused_margin(
+                    w_g.reshape(shape),
+                    ratio.reshape(shape),
+                    jnp.broadcast_to(shift, ratio.shape).reshape(shape),
+                    batch.val,
+                )
+                w_cur = w_cur2.reshape(-1)
+            else:
+                w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
+                contrib = w_cur.reshape(shape) * batch.val
+            # --- the ONLY cross-shard traffic: the per-example margin ---
+            z = dl.margin_psum(cfg, contrib)
+            if cfg.use_bias:
+                z = z + state.b
+            loss, gz = lt._grad_z(cfg, z, batch.y)
+            neg_eta_g = (-eta * (gz[:, None] * batch.val)).reshape(-1)  # [B*p]
+        with jax.named_scope(phases.SCATTER):
+            psi_new = state_compress.roundtrip(
+                jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
+                cfg.state_dtype,
+                integer=True,
+            )
+            upd = jnp.stack([w_cur, psi_new], axis=1)
+            wpsi = state.wpsi.at[idx_f].set(upd)
+            wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
+            b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
 

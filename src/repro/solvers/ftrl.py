@@ -44,9 +44,10 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
-from repro.core import state_compress
+from repro.core import phases, state_compress
 
 from .api import Solver
 
@@ -101,47 +102,50 @@ class FTRLSolver(Solver):
         from repro.core import linear_trainer as lt
 
         alpha = jnp.asarray(hp.eta_scale, jnp.float32)
-        idx_f = batch.idx.reshape(-1)
-        g3 = state.wpsi[idx_f]  # [B*p, 3] single gather: (w, z, n) rows
-        z_g, n_g = g3[:, 1], g3[:, 2]
-        shape = batch.idx.shape
-        if lt.fused_enabled(cfg):
-            # ONE whole-step tile pass: apply-at-read weights, predict,
-            # loss gradient, AdaGrad deltas (backend.ftrl_fused_step)
-            _, dz2, dn2, gz, loss = bk.ftrl_fused_step(
-                z_g.reshape(shape),
-                n_g.reshape(shape),
-                batch.val,
-                batch.y,
-                state.b,
-                alpha,
-                cfg.ftrl_beta,
-                hp.lam1,
-                hp.lam2,
-                loss=cfg.loss,
-                use_bias=cfg.use_bias,
-            )
-            dz, dn = dz2.reshape(-1), dn2.reshape(-1)
-        else:
-            # apply-at-read: current weights straight from (z, n) — no catch-up
-            w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
-            zlin = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
-            loss, gz = lt._grad_z(cfg, zlin, batch.y)
-            g_w = (gz[:, None] * batch.val).reshape(-1)  # [B*p]
-            dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
-        # scatter-ADD deltas (duplicates accumulate); the w column stays
-        # stale — reads always derive from (z, n), flush rematerializes it
-        wpsi = state.wpsi.at[idx_f, 1].add(dz)
-        wpsi = wpsi.at[idx_f, 2].add(dn)
-        if cfg.state_dtype != "f32":
-            # compress-on-write (DESIGN.md §13): the touched (z, n) rows
-            # round-trip the storage grid AFTER the scatter-ADD settles —
-            # duplicate gathers see identical final values, so the
-            # scatter-SET of the round-tripped image stays consistent
-            zn = wpsi[idx_f]
-            wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
-            wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
-        b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
+        with jax.named_scope(phases.GATHER):
+            idx_f = batch.idx.reshape(-1)
+            g3 = state.wpsi[idx_f]  # [B*p, 3] single gather: (w, z, n) rows
+            z_g, n_g = g3[:, 1], g3[:, 2]
+            shape = batch.idx.shape
+        with jax.named_scope(phases.KERNEL):
+            if lt.fused_enabled(cfg):
+                # ONE whole-step tile pass: apply-at-read weights, predict,
+                # loss gradient, AdaGrad deltas (backend.ftrl_fused_step)
+                _, dz2, dn2, gz, loss = bk.ftrl_fused_step(
+                    z_g.reshape(shape),
+                    n_g.reshape(shape),
+                    batch.val,
+                    batch.y,
+                    state.b,
+                    alpha,
+                    cfg.ftrl_beta,
+                    hp.lam1,
+                    hp.lam2,
+                    loss=cfg.loss,
+                    use_bias=cfg.use_bias,
+                )
+                dz, dn = dz2.reshape(-1), dn2.reshape(-1)
+            else:
+                # apply-at-read: current weights straight from (z, n) — no catch-up
+                w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
+                zlin = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
+                loss, gz = lt._grad_z(cfg, zlin, batch.y)
+                g_w = (gz[:, None] * batch.val).reshape(-1)  # [B*p]
+                dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
+        with jax.named_scope(phases.SCATTER):
+            # scatter-ADD deltas (duplicates accumulate); the w column stays
+            # stale — reads always derive from (z, n), flush rematerializes it
+            wpsi = state.wpsi.at[idx_f, 1].add(dz)
+            wpsi = wpsi.at[idx_f, 2].add(dn)
+            if cfg.state_dtype != "f32":
+                # compress-on-write (DESIGN.md §13): the touched (z, n) rows
+                # round-trip the storage grid AFTER the scatter-ADD settles —
+                # duplicate gathers see identical final values, so the
+                # scatter-SET of the round-tripped image stays consistent
+                zn = wpsi[idx_f]
+                wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
+                wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
+            b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=state.caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
 
@@ -155,33 +159,36 @@ class FTRLSolver(Solver):
         from repro.dist import linear as dl
 
         alpha = jnp.asarray(hp.eta_scale, jnp.float32)
-        idx_f = batch.idx.reshape(-1)
-        g3 = state.wpsi[idx_f]  # [B*p, 3] clip-gather; sentinel rows masked
-        z_g, n_g = g3[:, 1], g3[:, 2]
-        shape = batch.idx.shape
-        if lt.fused_enabled(cfg):
-            w_cur2, contrib = bk.ftrl_margin(
-                z_g.reshape(shape), n_g.reshape(shape), batch.val,
-                alpha, cfg.ftrl_beta, hp.lam1, hp.lam2,
-            )
-            w_cur = w_cur2.reshape(-1)
-        else:
-            w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
-            contrib = w_cur.reshape(shape) * batch.val
-        # --- the ONLY cross-shard traffic: the per-example margin ---
-        zlin = dl.margin_psum(cfg, contrib)
-        if cfg.use_bias:
-            zlin = zlin + state.b
-        loss, gz = lt._grad_z(cfg, zlin, batch.y)
-        g_w = (gz[:, None] * batch.val).reshape(-1)  # masked: 0 off-shard
-        dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
-        wpsi = state.wpsi.at[idx_f, 1].add(dz)
-        wpsi = wpsi.at[idx_f, 2].add(dn)
-        if cfg.state_dtype != "f32":
-            zn = wpsi[idx_f]
-            wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
-            wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
-        b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
+        with jax.named_scope(phases.GATHER):
+            idx_f = batch.idx.reshape(-1)
+            g3 = state.wpsi[idx_f]  # [B*p, 3] clip-gather; sentinel rows masked
+            z_g, n_g = g3[:, 1], g3[:, 2]
+            shape = batch.idx.shape
+        with jax.named_scope(phases.KERNEL):
+            if lt.fused_enabled(cfg):
+                w_cur2, contrib = bk.ftrl_margin(
+                    z_g.reshape(shape), n_g.reshape(shape), batch.val,
+                    alpha, cfg.ftrl_beta, hp.lam1, hp.lam2,
+                )
+                w_cur = w_cur2.reshape(-1)
+            else:
+                w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
+                contrib = w_cur.reshape(shape) * batch.val
+            # --- the ONLY cross-shard traffic: the per-example margin ---
+            zlin = dl.margin_psum(cfg, contrib)
+            if cfg.use_bias:
+                zlin = zlin + state.b
+            loss, gz = lt._grad_z(cfg, zlin, batch.y)
+            g_w = (gz[:, None] * batch.val).reshape(-1)  # masked: 0 off-shard
+            dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
+        with jax.named_scope(phases.SCATTER):
+            wpsi = state.wpsi.at[idx_f, 1].add(dz)
+            wpsi = wpsi.at[idx_f, 2].add(dn)
+            if cfg.state_dtype != "f32":
+                zn = wpsi[idx_f]
+                wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
+                wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
+            b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=state.caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
 

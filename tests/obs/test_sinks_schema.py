@@ -4,6 +4,7 @@ flagged line-by-line, and ``python -m repro.obs.report`` reproduces the
 lazy-work table (work ratio, effective speedup, nnz trajectory) from the
 events alone."""
 import json
+import time
 
 import numpy as np
 import pytest
@@ -42,6 +43,51 @@ class TestRoundTrip:
         assert events[3]["data"] == {"step": 8, "nnz": 17}
         for e in events:  # every event carries both stamps
             assert isinstance(e["ts"], float) and isinstance(e["t"], float)
+
+    def test_span_event_carries_its_start_on_the_profiler_clock(self, tmp_path):
+        path = tmp_path / "run.jsonl"
+        with obs.run_logger(str(path), "train") as logger:
+            before = time.time_ns()
+            with obs.span("train.run", steps=3):
+                pass
+            after = time.time_ns()
+            logger.span("train.round", 0.25)  # a caller-timed span has no start
+        events, errors = schema.load(str(path))
+        assert errors == []
+        assert before <= events[1]["start_ns"] <= after
+        assert events[1]["attrs"] == {"steps": 3}
+        assert "start_ns" not in events[2]
+        errs = schema.validate_event(
+            {"kind": "span", "ts": 1.0, "t": 0.0, "name": "x", "dur_s": 0.1, "attrs": {},
+             "start_ns": 1.5}
+        )
+        assert any("span.start_ns must be an int" in e for e in errs)
+
+    def test_span_start_lines_up_with_its_profile_annotation(self, tmp_path):
+        """The logged start and the profiler's record of the annotation agree:
+        a profile's events sit at its ``profile_start_time`` plus their own
+        start.  The bound is loose for a loaded test machine; another clock
+        (the monotonic one) would miss by years."""
+        from jax.profiler import ProfileData
+
+        log = tmp_path / "run.jsonl"
+        with obs.run_logger(str(log), "train"):
+            with obs.profile_to(str(tmp_path / "prof")):
+                with obs.span("train.aligned"):
+                    time.sleep(0.001)
+        start_ns = schema.load(str(log))[0][1]["start_ns"]
+        pb = sorted((tmp_path / "prof").rglob("*.xplane.pb"))[-1]
+        pd = ProfileData.from_file(str(pb))
+        t0 = [v for p in pd.planes for k, v in p.stats if k == "profile_start_time"]
+        starts = [
+            int(ev.start_ns)
+            for p in pd.planes
+            for line in p.lines
+            for ev in line.events
+            if ev.name == "train.aligned"
+        ]
+        assert len(t0) == 1 and len(starts) == 1, (t0, starts)
+        assert abs(t0[0] + starts[0] - start_ns) < 50_000_000
 
     def test_none_path_is_noop(self):
         with obs.run_logger(None, "train") as logger:
