@@ -22,7 +22,7 @@ into one [d, state_cols] f32 array — ``(w, psi)`` for the DP-cache solvers
 With separate arrays, XLA-CPU fuses the psi/w gathers into downstream
 consumers, keeps both buffers live across the scatters, and inserts two full
 O(d) copies per step — 245us/step at d=260,941.  The packed layout makes the
-step a single gather -> single scatter read-modify-write chain that buffer-
+step a gather -> scatter read-modify-write chain on one buffer that buffer-
 assigns in place: 18us/step (13.6x), restoring the paper's O(p) behaviour.
 
 Both trainers share prediction code and exploit sparsity when predicting

@@ -1,12 +1,12 @@
 """Cache-based delayed-regularization solvers: the paper's SGD and FoBoS
 flavors, refactored out of ``core.linear_trainer`` onto the Solver
-interface **bitwise-identically** (the step/flush/read bodies below ARE the
-pre-refactor code, moved; tests/solvers pins this with an inline copy of
-the old closure).
+interface **bitwise-identically** (the step/flush/read bodies below are the
+pre-refactor code, moved, with the touched state read and written per
+column; tests/solvers pins this with an inline copy of the old closure).
 
 The whole family shares one structure — the DP caches are the engine:
 
-  touched step:  extend cache slot i+1, gather (w, psi) rows, replay the
+  touched step:  extend cache slot i+1, gather the touched (w, psi), replay the
                  missed regularization for tau in [psi, i) in closed form,
                  predict, scatter back (caught-up w, psi=i) + gradient.
   flush:         one (ratio, shift) pair per coordinate from the caches,
@@ -28,6 +28,27 @@ from repro.core.dp_caches import FOBOS, SGD
 from repro.core.schedules import validate_schedule
 
 from .api import Solver
+
+
+# The touched (w, psi) state is read and written one column at a time: the
+# chip keeps the packed [.., d, 2] state column-major, so a row access costs
+# a relayout of the whole state into a layout padded to 128 lanes.
+def gather_touched(wpsi: jnp.ndarray, idx_f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(w, psi)`` of the touched features ``idx_f`` ([B*p] each)."""
+    return wpsi[idx_f, 0], wpsi[idx_f, 1].astype(jnp.int32)
+
+
+def write_back(cfg, wpsi, idx_f, w_cur, i, neg_eta_g) -> jnp.ndarray:
+    """Set the caught-up ``w`` and ``psi = i`` (duplicates identical), then
+    scatter-ADD the loss-gradient step (duplicates accumulate).  psi
+    round-trips its storage grid on write (exact by ``validate``; the f32
+    default is the identity)."""
+    psi_new = state_compress.roundtrip(
+        jnp.broadcast_to(i.astype(jnp.float32), w_cur.shape), cfg.state_dtype, integer=True
+    )
+    wpsi = wpsi.at[idx_f, 0].set(w_cur)
+    wpsi = wpsi.at[idx_f, 1].set(psi_new)
+    return wpsi.at[idx_f, 0].add(neg_eta_g)
 
 
 class LazyCacheSolver(Solver):
@@ -73,10 +94,7 @@ class LazyCacheSolver(Solver):
                 state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
             )
             idx_f = batch.idx.reshape(-1)
-            # --- single gather: (w, psi) rows for the touched features ---
-            g2 = state.wpsi[idx_f]  # [B*p, 2]
-            w_g = g2[:, 0]
-            psi_g = g2[:, 1].astype(jnp.int32)
+            w_g, psi_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
             if fused:
                 # (ratio, shift) from the caches in XLA — tiny O(B*p) gathers
@@ -107,18 +125,7 @@ class LazyCacheSolver(Solver):
                 loss, gz = lt._grad_z(cfg, z, batch.y)
                 neg_eta_g = -eta * (gz[:, None] * batch.val).reshape(-1)  # [B*p]
         with jax.named_scope(phases.SCATTER):
-            # --- write back: set (caught-up w, psi=i) — duplicates identical —
-            # then scatter-ADD the loss-gradient step (duplicates accumulate) ---
-            # psi round-trips its storage grid on write (exact by validate();
-            # the f32 default is the identity)
-            psi_new = state_compress.roundtrip(
-                jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
-                cfg.state_dtype,
-                integer=True,
-            )
-            upd = jnp.stack([w_cur, psi_new], axis=1)
-            wpsi = state.wpsi.at[idx_f].set(upd)
-            wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
+            wpsi = write_back(cfg, state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         # reg for step i itself stays pending (applied at next touch / flush)
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
@@ -139,9 +146,8 @@ class LazyCacheSolver(Solver):
                 state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
             )
             idx_f = batch.idx.reshape(-1)
-            g2 = state.wpsi[idx_f]  # [B*p, 2] clip-gather; sentinel rows masked
-            w_g = g2[:, 0]
-            psi_g = g2[:, 1].astype(jnp.int32)
+            # clip-gather; sentinel lanes are masked by their zero values
+            w_g, psi_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
             if fused:
                 ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
@@ -165,14 +171,7 @@ class LazyCacheSolver(Solver):
             loss, gz = lt._grad_z(cfg, z, batch.y)
             neg_eta_g = (-eta * (gz[:, None] * batch.val)).reshape(-1)  # [B*p]
         with jax.named_scope(phases.SCATTER):
-            psi_new = state_compress.roundtrip(
-                jnp.broadcast_to(state.i.astype(jnp.float32), w_cur.shape),
-                cfg.state_dtype,
-                integer=True,
-            )
-            upd = jnp.stack([w_cur, psi_new], axis=1)
-            wpsi = state.wpsi.at[idx_f].set(upd)
-            wpsi = wpsi.at[idx_f, 0].add(neg_eta_g)
+            wpsi = write_back(cfg, state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)

@@ -116,6 +116,40 @@ def test_sharded_fit_matches_unsharded(subproc):
     assert out.count("OK ") >= 13
 
 
+COLUMNS = r"""
+import jax
+import numpy as np
+import jax.numpy as jnp
+
+from repro._testing import assert_column_access, state_accesses
+from repro.core import linear_trainer as lt
+from repro.dist import linear as dl
+
+R, B, p = 4, 2, 3
+rng = np.random.default_rng(0)
+batches = lt.SparseBatch(
+    jnp.asarray(rng.integers(0, 97, size=(R, B, p)).astype(np.int32)),
+    jnp.asarray(rng.normal(size=(R, B, p)).astype(np.float32)),
+    jnp.asarray((rng.random(size=(R, B)) < 0.5).astype(np.float32)),
+)
+for solver in ("sgd", "fobos", "trunc"):
+    for fused in (True, False):
+        cfg = lt.LinearConfig(dim=97, round_len=R, solver=solver, fused=fused,
+                              lam1=0.01, lam2=0.005, trunc_k=2, mesh=4)
+        _, ds, _ = dl.shard_info(cfg)
+        jaxpr = jax.make_jaxpr(lt.make_round_fn(cfg, "lazy"))(lt.init_state(cfg), batches)
+        assert_column_access(state_accesses(jaxpr.jaxpr, ds, 2), B * p, 2)
+        print(f"OK {solver} fused={fused}")
+"""
+
+
+def test_sharded_step_touches_the_state_by_column(subproc):
+    """sharded_update gathers and writes back the shard's (w, psi) rows one
+    column at a time, as touched_update does (tests/solvers)."""
+    out = subproc(COLUMNS, n_devices=4)
+    assert out.count("OK ") == 6
+
+
 def _cfg4(**kw):
     from repro.core import linear_trainer as lt
 

@@ -5,7 +5,8 @@ Each case lowers the public kernel wrapper with ``interpret=False`` against
 shapes placed on one device of a described ``v5e:2x2`` topology and asserts
 that the compiled program holds the Mosaic call (``tpu_custom_call``).  The
 TPU compiler refuses here what interpret mode accepts: block shapes that
-break the (8, 128) tiling, or more VMEM than a kernel may use.
+break the (8, 128) tiling, or more VMEM than a kernel may use.  One case
+compiles the whole Medline round program and checks the loop's layout.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -14,6 +15,7 @@ All cases live in this one file so that a single worker loads it.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +130,47 @@ CASES = {
 def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = CASES[case](one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _while_bodies(text: str) -> list:
+    """The text of each ``while`` loop's body computation in compiled HLO."""
+    names = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    heads = r"^%?(" + "|".join(map(re.escape, names)) + r") [^\n]*\{$"
+    return re.findall(heads + r"\n(.*?)^\}$", text, re.M | re.S) if names else []
+
+
+def test_medline_round_keeps_the_state_layout(one_chip, monkeypatch):
+    """The lazy step at the Medline shape (FoBoS, compiled kernels, rounds of
+    2048 x 8 x 128) reads and writes the touched (w, psi) state in the
+    chip's own layout: the loop body holds no copy of the whole state."""
+    from repro.backend import pallas as pallas_backend
+    from repro.core import LinearConfig, ScheduleConfig, SparseBatch, init_state, make_round_fn
+
+    for mod in (ops, pallas_backend):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    cfg = LinearConfig(
+        dim=MEDLINE_DIM,
+        solver="fobos",
+        backend="pallas",
+        round_len=2048,
+        lam1=2e-4,
+        lam2=1e-4,
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=0.02, t0=200.0),
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_state(cfg)),
+    )
+    R, B, P = cfg.round_len, 8, 128
+    batches = SparseBatch(
+        idx=jax.ShapeDtypeStruct((R, B, P), jnp.int32, sharding=one_chip),
+        val=_f32(one_chip, R, B, P),
+        y=_f32(one_chip, R, B),
+    )
+    text = make_round_fn(cfg, "lazy").lower(state, batches).compile().as_text()
+    assert "tpu_custom_call" in text
+    bodies = _while_bodies(text)
+    assert bodies
+    state_copy = re.compile(r"= f32\[%d,2\]\{[^}]*\} copy\(" % MEDLINE_DIM)
+    for _, body in bodies:
+        assert not state_copy.search(body)
