@@ -14,6 +14,12 @@ by phase without changing what runs.
   storage-grid round-trips, the bias step;
 * ``FLUSH``: the round flush (``core.linear_trainer.flush``,
   ``dist.linear.local_flush``).
+
+``MARGIN`` is no fifth phase but a scope inside ``KERNEL``: on a feature
+mesh, the per-example margin's cross-shard reduction
+(``dist.linear.margin_psum``: the psum and the ops around it), so that a
+profile can read the collective's own device time.  Single-device
+programs hold no psum and never name it.
 """
 
 GATHER = "lazy.gather"
@@ -21,3 +27,4 @@ KERNEL = "lazy.kernel"
 SCATTER = "lazy.scatter"
 FLUSH = "lazy.flush"
 PHASES = (GATHER, KERNEL, SCATTER, FLUSH)
+MARGIN = "lazy.margin"

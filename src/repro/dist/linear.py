@@ -45,6 +45,7 @@ Validated on CPU host meshes: ``XLA_FLAGS=--xla_force_host_platform_device_count
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -147,18 +148,20 @@ def route_batch(cfg, batch: SparseBatch) -> SparseBatch:
 
 def margin_psum(cfg, contrib: jnp.ndarray) -> jnp.ndarray:
     """Reduce the masked per-slot margin contributions ``[B, p]`` to the
-    per-example margin ``[B]`` — the ONLY cross-shard traffic of a step."""
-    if cfg.shard_margin == "exact":
-        # column-aligned: each slot is owned by exactly one shard, so the
-        # psum adds zeros — exact — and the column reduction then runs in
-        # the unsharded order (bitwise parity on the reference backend)
-        return jnp.sum(jax.lax.psum(contrib, cfg.feature_axis), axis=-1)
-    part = jnp.sum(contrib, axis=-1)
-    if cfg.shard_margin == "quantized":
-        from . import compress
+    per-example margin ``[B]`` — the ONLY cross-shard traffic of a step.
+    Its ops carry the ``lazy.margin`` scope (``core.phases.MARGIN``)."""
+    with jax.named_scope(phases.MARGIN):
+        if cfg.shard_margin == "exact":
+            # column-aligned: each slot is owned by exactly one shard, so the
+            # psum adds zeros — exact — and the column reduction then runs in
+            # the unsharded order (bitwise parity on the reference backend)
+            return jnp.sum(jax.lax.psum(contrib, cfg.feature_axis), axis=-1)
+        part = jnp.sum(contrib, axis=-1)
+        if cfg.shard_margin == "quantized":
+            from . import compress
 
-        return compress.quantized_psum(part, cfg.feature_axis)
-    return jax.lax.psum(part, cfg.feature_axis)
+            return compress.quantized_psum(part, cfg.feature_axis)
+        return jax.lax.psum(part, cfg.feature_axis)
 
 
 def make_local_step_hp(cfg):
@@ -201,22 +204,36 @@ def _local_predict(cfg, solver, state: LinearState, batch: SparseBatch, hp: Hype
 
 
 def init_state(cfg, w0=None) -> LinearState:
-    """Packed state padded to ``n * ds`` rows and placed row-sharded over
-    the feature mesh; bias/caches/clock replicated."""
-    n, ds, d_pad = shard_info(cfg)
-    wpsi = lt._solver(cfg).init_cols(cfg, w0)
-    if d_pad > cfg.dim:
-        wpsi = jnp.concatenate(
-            [wpsi, jnp.zeros((d_pad - cfg.dim, wpsi.shape[1]), jnp.float32)]
+    """Packed state padded to ``n * ds`` rows, row-sharded over the feature
+    mesh; bias/caches/clock replicated.  One program partitioned by its
+    output shardings builds it, so each device makes (and, given ``w0``,
+    seeds) its own ``[ds, cols]`` slab: no device ever holds the whole
+    ``[d_pad, cols]`` state, which at production ``d`` outgrows one chip."""
+    d_pad = shard_info(cfg)[2]
+    mesh = feature_mesh(cfg)
+    padded = dataclasses.replace(cfg, dim=d_pad)
+
+    def build(w0):
+        if w0 is not None:
+            # the seed padded with zeros (a zero seed reads back weight 0
+            # from zero state: inert), one [ds] slice a device
+            w0 = jax.lax.with_sharding_constraint(
+                jnp.pad(jnp.asarray(w0, jnp.float32), (0, d_pad - cfg.dim)),
+                NamedSharding(mesh, P(cfg.feature_axis)),
+            )
+        return LinearState(
+            wpsi=lt._solver(cfg).init_cols(padded, w0),
+            b=jnp.zeros((), jnp.float32),
+            caches=dp_caches.init_caches(cfg.round_len),
+            i=jnp.zeros((), jnp.int32),
+            t=jnp.zeros((), jnp.int32),
         )
-    state = LinearState(
-        wpsi=wpsi,
-        b=jnp.zeros((), jnp.float32),
-        caches=dp_caches.init_caches(cfg.round_len),
-        i=jnp.zeros((), jnp.int32),
-        t=jnp.zeros((), jnp.int32),
-    )
-    return jax.device_put(state, state_shardings(cfg))
+
+    return jax.jit(
+        build,
+        in_shardings=NamedSharding(mesh, P()),  # a seed may sit on any one device
+        out_shardings=state_shardings(cfg, mesh),
+    )(w0)
 
 
 def make_lazy_step(cfg):

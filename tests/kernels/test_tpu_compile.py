@@ -174,3 +174,65 @@ def test_medline_round_keeps_the_state_layout(one_chip, monkeypatch):
     state_copy = re.compile(r"= f32\[%d,2\]\{[^}]*\} copy\(" % MEDLINE_DIM)
     for _, body in bodies:
         assert not state_copy.search(body)
+
+
+CRITEO_TB_DIM = 2**30
+
+
+def test_criteo_tb_sharded_round_fits_four_chips(topo, one_chip, monkeypatch):
+    """The feature-sharded FTRL round at the Criteo 1TB shape (d = 2^30 over
+    the four chips of a v5e:2x2, compiled kernels, rounds of 2048 x 8 x 40):
+    each device holds one [2^28, 3] slab, the loop body holds exactly one
+    all-reduce (the margin psum, scoped ``lazy.margin``), and the program
+    fits one chip's 16 GiB.  The state itself is built slab by slab."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.backend import pallas as pallas_backend
+    from repro.core import LinearConfig, ScheduleConfig, SparseBatch, init_state, make_round_fn
+    from repro.dist import linear as dl
+
+    for mod in (ops, pallas_backend):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    monkeypatch.setattr(
+        dl, "feature_mesh",
+        lambda cfg: Mesh(np.array(topo.devices[: cfg.mesh]), (cfg.feature_axis,)),
+    )
+    cfg = LinearConfig(
+        dim=CRITEO_TB_DIM,
+        solver="ftrl",
+        backend="pallas",
+        round_len=2048,
+        lam1=1.0,
+        lam2=1.0,
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=0.1, t0=200.0),
+        mesh=4,
+    )
+    slab = "f32[%d,3]" % (CRITEO_TB_DIM // 4)
+    whole = "f32[%d,3]" % CRITEO_TB_DIM
+    init_text = jax.jit(lambda: init_state(cfg)).lower().compile().as_text()
+    assert slab in init_text and whole not in init_text
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(lambda: init_state(cfg)),
+        dl.state_shardings(cfg),
+    )
+    everywhere = NamedSharding(dl.feature_mesh(cfg), PartitionSpec())
+    R, B, P = cfg.round_len, 8, 40
+    batches = SparseBatch(
+        idx=jax.ShapeDtypeStruct((R, B, P), jnp.int32, sharding=everywhere),
+        val=_f32(everywhere, R, B, P),
+        y=_f32(everywhere, R, B),
+    )
+    compiled = make_round_fn(cfg, "lazy").lower(state, batches).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert slab in text and whole not in text
+    bodies = [body for _, body in _while_bodies(text)]
+    reduce = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .* all-reduce(?:-start)?\(.*$", re.M)
+    found = [line for body in bodies for line in reduce.findall(body)]
+    assert len(found) == 1, found
+    assert "lazy.margin" in found[0]
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert used + mem.temp_size_in_bytes < 16 * 2**30, mem
