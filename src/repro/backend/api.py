@@ -118,6 +118,13 @@ class KernelBackend:
         sqrt(n))/alpha + lam2)``.  All hypers may be traced scalars."""
         raise NotImplementedError
 
+    def ftrl_flush(self, wpsi, alpha, beta, lam1, lam2):
+        """The FTRL round flush over a packed ``[d, 3]`` ``(w, z, n)`` state:
+        the state with every row's w rebuilt by :meth:`ftrl_read` from its
+        own ``(z, n)``, z and n unchanged, in one pass over the state as it
+        lies (the caller donates it).  All hypers may be traced scalars."""
+        raise NotImplementedError
+
     def ftrl_update(self, w, n, g, alpha):
         """Per-coordinate AdaGrad FTRL update deltas for flat rows:
         ``sigma = (sqrt(n + g^2) - sqrt(n)) / alpha``, returns

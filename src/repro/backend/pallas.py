@@ -17,6 +17,7 @@ from repro.kernels import (
     dp_margin,
     enet_apply,
     enet_prox,
+    ftrl_flush,
     ftrl_fused_step,
     ftrl_margin,
     ftrl_read,
@@ -88,6 +89,9 @@ class PallasBackend(KernelBackend):
 
     def ftrl_read(self, z, n, alpha, beta, lam1, lam2):
         return ftrl_read(z, n, alpha, beta, lam1, lam2)
+
+    def ftrl_flush(self, wpsi, alpha, beta, lam1, lam2):
+        return ftrl_flush(wpsi, alpha, beta, lam1, lam2)
 
     def ftrl_update(self, w, n, g, alpha):
         return ftrl_update(w, n, g, alpha)

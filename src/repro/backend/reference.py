@@ -91,6 +91,13 @@ class ReferenceBackend(KernelBackend):
         w = (jnp.sign(z) * lam1 - z) / denom
         return jnp.where(jnp.abs(z) <= lam1, 0.0, w)
 
+    def ftrl_flush(self, wpsi, alpha, beta, lam1, lam2):
+        # elementwise over the state in whatever shape it has: w from the
+        # row's own (z, n), selected into column 0
+        w = self.ftrl_read(wpsi[..., 1:2], wpsi[..., 2:3], alpha, beta, lam1, lam2)
+        col = jax.lax.broadcasted_iota(jnp.int32, wpsi.shape, wpsi.ndim - 1)
+        return jnp.where(col == 0, w, wpsi)
+
     def ftrl_update(self, w, n, g, alpha):
         g2 = g * g
         inv_alpha = 1.0 / alpha  # see ftrl_read

@@ -11,7 +11,8 @@ and attention hot path dispatches here when the pallas backend is selected
   split catchup-then-update), plus the gradient-free apply used by flushes
 * enet_prox — dense elastic-net shrink sweep (dense baseline / flush shrink)
 * ftrl — FTRL-Proximal apply-at-read + per-coordinate AdaGrad update deltas
-  (the `ftrl` solver's elementwise hot paths, repro.solvers.ftrl)
+  (the `ftrl` solver's elementwise hot paths, repro.solvers.ftrl), and its
+  round flush over the state's own tiles
 * margin — the shard-local pre-psum half of the fused step (catch-up /
   apply-at-read + per-slot margin contributions) for feature-sharded
   training (repro.dist.linear, DESIGN.md §16)
@@ -36,6 +37,7 @@ from .ops import (
     dp_margin,
     enet_apply,
     enet_prox,
+    ftrl_flush,
     ftrl_fused_step,
     ftrl_margin,
     ftrl_read,
@@ -52,6 +54,7 @@ __all__ = [
     "enet_apply",
     "enet_prox",
     "flash_attention",
+    "ftrl_flush",
     "ftrl_fused_step",
     "ftrl_margin",
     "ftrl_read",

@@ -25,7 +25,7 @@ from repro.core.lazy_enet import catchup_factors
 
 from .common import default_interpret
 from .enet_prox import enet_prox_kernel
-from .ftrl import ftrl_read_rows_kernel, ftrl_update_rows_kernel
+from .ftrl import ftrl_flush_tiles_kernel, ftrl_read_rows_kernel, ftrl_update_rows_kernel
 from .fused_step import dp_fused_step_kernel, ftrl_fused_step_kernel
 from .lazy_enet import enet_apply_rows_kernel, lazy_enet_rows_kernel
 from .margin import dp_margin_rows_kernel, ftrl_margin_rows_kernel
@@ -186,6 +186,33 @@ def ftrl_read(
         block_rows=block_rows, block_cols=block_cols, interpret=interpret,
     )
     return out.reshape(-1)[:cnt]
+
+
+@functools.partial(jax.jit, static_argnames=("block_tiles", "interpret"))
+def ftrl_flush(
+    wpsi: jnp.ndarray,  # [d, 3] packed (w, z, n) state
+    alpha,  # dynamic f32 scalars (may be traced per-config)
+    beta,
+    lam1,
+    lam2,
+    *,
+    block_tiles: int = 512,
+    interpret: bool | None = None,
+):
+    """The FTRL round flush: ``wpsi`` with its w column rebuilt from each
+    row's own ``(z, n)`` (the arithmetic of :func:`ftrl_read`), z and n
+    unchanged.  ``[d, 3]`` is viewed as its own (3, 128) tiles, a bitcast of
+    the chip's layout; a ragged ``d`` is zero-padded to whole tiles first
+    (zero rows read 0) and cut back after."""
+    if interpret is None:
+        interpret = default_interpret()
+    d = wpsi.shape[0]
+    assert wpsi.shape == (d, 3), wpsi.shape
+    tiles = _pad_to(wpsi, 128, 1).reshape(-1, 128, 3).transpose(0, 2, 1)
+    out = ftrl_flush_tiles_kernel(
+        tiles, alpha, beta, lam1, lam2, block_tiles=block_tiles, interpret=interpret
+    )
+    return out.transpose(0, 2, 1).reshape(-1, 3)[:d]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols", "interpret"))

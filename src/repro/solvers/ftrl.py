@@ -207,11 +207,15 @@ class FTRLSolver(Solver):
     def flush(self, cfg, state, hp, bk):
         """No caches to rebase — flushing just rematerializes the w column
         (so raw ``weights()`` views and warm-start seeding read current
-        values) and reopens the round counter."""
+        values) and reopens the round counter.  ``bk.ftrl_flush`` rebuilds
+        the ``[d, 3]`` state (one vmapped lane, or a shard's slab) in one
+        pass over its own layout."""
         from repro.core import linear_trainer as lt
 
-        w = self.read_weights(cfg, state, hp, bk)
-        wpsi = jnp.stack([w, state.wpsi[:, 1], state.wpsi[:, 2]], axis=1)
+        wpsi = bk.ftrl_flush(
+            state.wpsi,
+            jnp.asarray(hp.eta_scale, jnp.float32), cfg.ftrl_beta, hp.lam1, hp.lam2,
+        )
         return lt.LinearState(
             wpsi=wpsi, b=state.b, caches=state.caches, i=jnp.zeros_like(state.i), t=state.t
         )

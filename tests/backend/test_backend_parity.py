@@ -199,3 +199,23 @@ def test_swap_weights_preserves_backend(rng):
     svc = LinearService(cfg, ServiceConfig(p_max=8, micro_batch=4))
     svc.swap_weights(np.zeros(DIM, np.float32), cfg=dataclasses.replace(cfg, lam1=5e-4))
     assert svc.cfg.backend == "pallas"
+
+
+@pytest.mark.parametrize("dim", [1030, 9 * 128])
+def test_ftrl_flush_kernel_matches_reference(dim, rng):
+    """The Pallas flush over the state's (3, 128) tiles, with a ragged dim
+    padded to whole tiles and a last block of tiles cut short, against the
+    reference backend's elementwise flush."""
+    from repro import backend as kb
+    from repro.kernels import ops
+
+    wpsi = np.stack(
+        [rng.normal(size=dim), rng.normal(scale=2.0, size=dim), rng.uniform(0.0, 4.0, size=dim)],
+        axis=1,
+    ).astype(np.float32)
+    hypers = (jnp.float32(0.1), 1.0, 0.5, 0.25)  # alpha, beta, lam1, lam2
+    want = np.asarray(kb.get_backend("reference").ftrl_flush(jnp.asarray(wpsi), *hypers))
+    got = np.asarray(ops.ftrl_flush(jnp.asarray(wpsi), *hypers, block_tiles=4))
+    np.testing.assert_array_equal(got[:, 1:], wpsi[:, 1:])
+    np.testing.assert_allclose(got[:, 0], want[:, 0], rtol=1e-6, atol=0)
+    assert np.sum(got[:, 0] == 0.0) > 0 and np.sum(got[:, 0] != 0.0) > 0

@@ -27,6 +27,7 @@ from repro.kernels.flash_attn import flash_attention
 
 MEDLINE_DIM = 260_941
 HASHED_DIM = 2**24
+CRITEO_DIM = 2**26
 # stablelm_3b at its published widths: 32 heads of 80
 HEADS, HEAD_DIM = 32, 80
 
@@ -85,6 +86,10 @@ def _enet_apply(s):
     return ops.enet_apply.lower(w, w, w, interpret=False)
 
 
+def _ftrl_flush(s):
+    return ops.ftrl_flush.lower(_f32(s, CRITEO_DIM, 3), 0.1, 1.0, 1.0, 1.0, interpret=False)
+
+
 def _dp_margin(s):
     bp = _f32(s, 32, 128)
     return ops.dp_margin.lower(bp, bp, bp, bp, interpret=False)
@@ -114,6 +119,7 @@ CASES = {
     "screen_mask": _screen,
     "enet_apply": _enet_apply,
     "dp_margin": _dp_margin,
+    "ftrl_flush": _ftrl_flush,
     "flash_prefill": functools.partial(
         _flash, batch=1, sq=256, skv=256, block_q=128, block_k=128, offset=0
     ),
@@ -174,6 +180,64 @@ def test_medline_round_keeps_the_state_layout(one_chip, monkeypatch):
     state_copy = re.compile(r"= f32\[%d,2\]\{[^}]*\} copy\(" % MEDLINE_DIM)
     for _, body in bodies:
         assert not state_copy.search(body)
+
+
+def _outside_loops(text: str) -> str:
+    """Compiled HLO text with every ``while`` loop's body taken out."""
+    for name, body in _while_bodies(text):
+        text = text.replace(body, "")
+    return text
+
+
+def test_criteo_round_flushes_the_state_in_place(one_chip, monkeypatch):
+    """The FTRL round at the Criteo shape (d = 2^26, compiled kernels,
+    rounds of 2048 x 8 x 40) flushes the donated [d, 3] state in its own
+    layout: outside the loop there is no flat read kernel, no [2^18, 256]
+    tiling of a column, no [d, 2] copy of (z, n), and less temporary memory
+    than half a state (a second whole state is 1 GiB).  The flush is one
+    kernel over the state's (3, 128) tiles, a bitcast of the state, written
+    over its input."""
+    from repro.backend import pallas as pallas_backend
+    from repro.core import LinearConfig, ScheduleConfig, SparseBatch, init_state, make_round_fn
+
+    for mod in (ops, pallas_backend):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    cfg = LinearConfig(
+        dim=CRITEO_DIM,
+        solver="ftrl",
+        backend="pallas",
+        round_len=2048,
+        lam1=1.0,
+        lam2=1.0,
+        schedule=ScheduleConfig(kind="inv_sqrt", eta0=0.1, t0=200.0),
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_state(cfg)),
+    )
+    R, B, P = cfg.round_len, 8, 40
+    batches = SparseBatch(
+        idx=jax.ShapeDtypeStruct((R, B, P), jnp.int32, sharding=one_chip),
+        val=_f32(one_chip, R, B, P),
+        y=_f32(one_chip, R, B),
+    )
+    compiled = make_round_fn(cfg, "lazy").lower(state, batches).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and _while_bodies(text)
+    outside = _outside_loops(text)
+    assert "ftrl_read_rows" not in outside
+    assert "f32[262144,256]" not in outside
+    zn_copy = re.compile(r"= f32\[%d,2\]\{[^}]*\} slice\(" % CRITEO_DIM)
+    assert not zn_copy.search(outside)
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, ", text)
+    flush = re.compile(
+        r"= f32\[%d,3,128\]\{[^}]*\} custom-call\(.*\bbitcast[.\d]*\).*"
+        r"tpu_custom_call.*output_to_operand_aliasing" % (CRITEO_DIM // 128)
+    )
+    assert len(flush.findall(outside)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= CRITEO_DIM * 3 * 4, mem
+    assert mem.temp_size_in_bytes < 512 * 2**20, mem
 
 
 CRITEO_TB_DIM = 2**30

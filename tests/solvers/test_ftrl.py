@@ -169,3 +169,167 @@ def test_ftrl_thresholds_to_exact_zeros(rng):
     assert np.sum(w == 0.0) > 0  # the heavy lam1 must zero some touched coords
     z = np.asarray(state.wpsi[:, 1])
     np.testing.assert_array_equal(w[np.abs(z) <= cfg.lam1], 0.0)
+
+
+# -- the round flush: w rebuilt in place from each row's own (z, n) ----------
+
+FLUSH_DIM = 1030  # not a multiple of 4: a four-way mesh pads two rows
+FLUSH_HYPERS = [(0.5, 0.01, 0.3), (1.0, 0.5, 0.1), (0.05, 0.2, 2.0)]  # (lam1, lam2, alpha)
+
+
+def _stale_wpsi(rng, dim):
+    """(w, z, n) rows with a stale w column and z on both sides of lam1."""
+    w = rng.normal(size=dim)
+    z = rng.normal(scale=2.0, size=dim) * (rng.uniform(size=dim) > 0.2)
+    n = rng.uniform(0.0, 4.0, size=dim) * (rng.uniform(size=dim) > 0.1)
+    return np.stack([w, z, n], axis=1).astype(np.float32)
+
+
+def _flush_cfg(backend):
+    lam1, lam2, alpha = FLUSH_HYPERS[1]
+    return LinearConfig(
+        dim=FLUSH_DIM, solver="ftrl", lam1=lam1, lam2=lam2, round_len=16, backend=backend,
+        schedule=ScheduleConfig(kind="constant", eta0=alpha),
+    )
+
+
+def _lane_hypers(k):
+    from repro.core import Hypers
+
+    lam1, lam2, alpha = FLUSH_HYPERS[k]
+    return Hypers(lam1=jnp.float32(lam1), lam2=jnp.float32(lam2), eta_scale=jnp.float32(alpha))
+
+
+def _read(cfg, wpsi, hp):
+    """The solver's own full read of a [d, 3] state, as its own program."""
+    import jax
+
+    sv = lt._solver(cfg)
+    state = init_state(cfg)._replace(wpsi=jnp.asarray(wpsi))
+    fn = jax.jit(lambda s, h: sv.read_weights(cfg, s, h, lt._backend(cfg.backend)))
+    return np.asarray(fn(state, hp))
+
+
+def _check_flushed(backend, got, wpsi, want):
+    np.testing.assert_array_equal(got[:, 1:], wpsi[:, 1:])  # z, n untouched
+    if backend == "reference":
+        np.testing.assert_array_equal(got[:, 0], want)
+    else:
+        np.testing.assert_allclose(got[:, 0], want, rtol=1e-6, atol=0)
+
+
+def _flush_single(backend, rng):
+    import jax
+
+    cfg = _flush_cfg(backend)
+    wpsi = _stale_wpsi(rng, FLUSH_DIM)
+    state = init_state(cfg)._replace(wpsi=jnp.asarray(wpsi), i=jnp.int32(5))
+    out = jax.jit(lambda s: lt.flush(cfg, s), donate_argnums=0)(state)
+    assert int(out.i) == 0
+    _check_flushed(backend, np.asarray(out.wpsi), wpsi, _read(cfg, wpsi, cfg.hypers()))
+
+
+def _flush_sweep(backend, rng):
+    """The sweeps' vmapped flush, each config with its own hypers."""
+    import jax
+
+    from repro.core import Hypers
+    from repro.sweeps.batched_trainer import HYPER_AXES, STATE_AXES
+
+    cfg = _flush_cfg(backend)
+    n_cfg = len(FLUSH_HYPERS)
+    wpsi = np.stack([_stale_wpsi(rng, FLUSH_DIM) for _ in range(n_cfg)])
+    one = init_state(cfg)
+    bstate = one._replace(
+        wpsi=jnp.asarray(wpsi),
+        b=jnp.zeros((n_cfg,), jnp.float32),
+        caches=jax.tree.map(lambda c: jnp.broadcast_to(c, (n_cfg,) + c.shape), one.caches),
+    )
+    lam1, lam2, alpha = (jnp.asarray(np.float32(h)) for h in zip(*FLUSH_HYPERS))
+    hp = Hypers(lam1=lam1, lam2=lam2, eta_scale=alpha)
+    vflush = jax.vmap(
+        lambda s, h: lt.flush(cfg, s, hp=h), in_axes=(STATE_AXES, HYPER_AXES), out_axes=STATE_AXES
+    )
+    out = np.asarray(jax.jit(vflush)(bstate, hp).wpsi)
+    for k in range(n_cfg):
+        _check_flushed(backend, out[k], wpsi[k], _read(cfg, wpsi[k], _lane_hypers(k)))
+
+
+def _flush_tenants(backend, rng):
+    """MultiLinearService's masked per-slot flush: flushed lanes rebuild w,
+    the lane left out of the mask stays bitwise as it was."""
+    from repro.serving.multi_service import MultiLinearService
+
+    svc = MultiLinearService(_flush_cfg(backend), n_slots=len(FLUSH_HYPERS))
+    for k, (lam1, lam2, alpha) in enumerate(FLUSH_HYPERS):
+        svc.add_tenant(f"t{k}", lam1=lam1, lam2=lam2, eta0=alpha)
+    g = svc.groups["ftrl"]
+    wpsi = np.stack([_stale_wpsi(rng, FLUSH_DIM) for _ in FLUSH_HYPERS])
+    g.bstate = g.bstate._replace(wpsi=jnp.asarray(wpsi))
+    mask = np.array([True, False, True])
+    out = np.asarray(g.flush_fn(g.bstate, g.hp(), jnp.asarray(mask)).wpsi)
+    for k in range(len(FLUSH_HYPERS)):
+        if mask[k]:
+            _check_flushed(backend, out[k], wpsi[k], _read(g.cfg, wpsi[k], g.hp_at(k)))
+        else:
+            np.testing.assert_array_equal(out[k], wpsi[k])
+
+
+_MESH_FLUSH = """
+import sys
+import jax, numpy as np
+from repro.core import LinearConfig, ScheduleConfig, init_state
+from repro.core import linear_trainer as lt
+from repro.dist import linear as dl
+
+backend, dim, lam1, lam2, alpha = sys.argv[1], int(sys.argv[2]), *map(float, sys.argv[3:6])
+cfg = LinearConfig(dim=dim, solver="ftrl", lam1=lam1, lam2=lam2, round_len=16, backend=backend,
+                   schedule=ScheduleConfig(kind="constant", eta0=alpha), mesh=4)
+wpsi = np.load(sys.argv[6])
+host = dl.host_template(cfg)._replace(wpsi=wpsi)
+out = jax.jit(lambda s: lt.flush(cfg, s), donate_argnums=0)(dl.place_state(cfg, host))
+np.save(sys.argv[7], np.asarray(out.wpsi))
+"""
+
+
+def _flush_mesh(backend, rng, tmp_path):
+    """Each shard flushes its own slab; the ceil-div padding rows stay zero."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    cfg = _flush_cfg(backend)
+    wpsi = _stale_wpsi(rng, FLUSH_DIM)
+    np.save(tmp_path / "in.npy", wpsi)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    src = str(Path(__file__).resolve().parents[2] / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    args = [backend, str(FLUSH_DIM), *map(str, FLUSH_HYPERS[1])]
+    args += [str(tmp_path / "in.npy"), str(tmp_path / "out.npy")]
+    proc = subprocess.run(
+        [sys.executable, "-c", _MESH_FLUSH, *args],
+        capture_output=True, text=True, timeout=600, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = np.load(tmp_path / "out.npy")
+    assert out.shape == (4 * -(-FLUSH_DIM // 4), 3)
+    np.testing.assert_array_equal(out[FLUSH_DIM:], 0.0)
+    _check_flushed(backend, out[:FLUSH_DIM], wpsi, _read(cfg, wpsi, cfg.hypers()))
+
+
+_FLUSH_CASES = {"single": _flush_single, "sweep": _flush_sweep, "tenants": _flush_tenants}
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+@pytest.mark.parametrize("case", [*_FLUSH_CASES, "mesh4"])
+def test_ftrl_flush_rebuilds_w_from_z_and_n(case, backend, rng, tmp_path):
+    """The round flush leaves z and n bitwise as they were and rebuilds the
+    w column as the solver's full read gives it: bitwise on the reference
+    backend, within 1e-6 relative against the flat Pallas read kernel."""
+    if case == "mesh4":
+        _flush_mesh(backend, rng, tmp_path)
+    else:
+        _FLUSH_CASES[case](backend, rng)
