@@ -43,9 +43,10 @@ class ReferenceBackend(KernelBackend):
         return jnp.sign(w) * jnp.maximum(jnp.abs(w) - shift, 0.0)
 
     def fused_step(self, w, ratio, shift, val, y, b, eta, *, loss, use_bias):
-        # the exact op sequence of the pre-fusion multi-op step, on the
-        # same [B, p] shapes — the solver's fused default stays BITWISE
-        # equal to the inlined pre-refactor closure (tests/solvers)
+        # the exact op sequence of the multi-op reference step
+        # (repro._testing.reference_step), on the same [B, p] shapes — the
+        # solver step stays BITWISE equal to it (tests/fused) and to the
+        # inlined pre-refactor closure (tests/solvers)
         from repro.core import linear_trainer as lt
 
         mag = jnp.abs(w) * ratio - shift

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -116,14 +115,6 @@ class LinearConfig:
     # kernel backend for the regularization hot paths (repro.backend):
     # None defers to use_backend()/$REPRO_BACKEND/platform default
     backend: Optional[str] = None
-    # fused whole-step kernel path (backend.fused_step, DESIGN.md §13):
-    # None defers to $REPRO_FUSED and then to True (fused is the default
-    # compute substrate); False keeps the multi-op reference step
-    fused: Optional[bool] = None
-    # storage grid for the non-weight state columns (psi / FTRL z, n):
-    # f32 (exact), bf16, or int8 shared-scale (core.state_compress —
-    # DESIGN.md §13 documents the error bounds and round_len limits)
-    state_dtype: str = "f32"
     # feature sharding (repro.dist.linear, DESIGN.md §16): mesh = number of
     # devices to partition the [d, state_cols] state over along
     # ``feature_axis``; None keeps every path single-device.  shard_margin
@@ -140,9 +131,6 @@ class LinearConfig:
         assert self.loss in (LOGISTIC, SQUARED), self.loss
         assert self.lam1 >= 0.0 and self.lam2 >= 0.0
         assert self.round_len < 2**24  # psi lives exactly in f32
-        from .state_compress import STATE_DTYPES
-
-        assert self.state_dtype in STATE_DTYPES, self.state_dtype
         if self.mesh is not None:
             assert isinstance(self.mesh, int) and self.mesh >= 1, self.mesh
             assert self.feature_axis, "feature_axis must be a non-empty name"
@@ -215,7 +203,7 @@ def init_state(cfg: LinearConfig, w0: Optional[jnp.ndarray] = None, mode: str = 
 
 def loss_and_grad_z(loss: str, z: jnp.ndarray, y: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-example loss and dLoss/dz for a loss kind — the single home of
-    the loss arithmetic, shared by the multi-op step, the backends' fused
+    the loss arithmetic, shared by the mesh step, the backends' fused
     whole-step ops, and the dense baseline (bitwise across all of them)."""
     if loss == LOGISTIC:
         # numerically stable BCE-with-logits
@@ -230,20 +218,6 @@ def loss_and_grad_z(loss: str, z: jnp.ndarray, y: jnp.ndarray) -> Tuple[jnp.ndar
 def _grad_z(cfg: LinearConfig, z: jnp.ndarray, y: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-example loss and dLoss/dz (cfg-keyed form of loss_and_grad_z)."""
     return loss_and_grad_z(cfg.loss, z, y)
-
-
-def fused_enabled(cfg: LinearConfig) -> bool:
-    """Whether the solver step routes through the backend's fused whole-step
-    op (trace-static, like backend/solver resolution): ``cfg.fused`` >
-    ``$REPRO_FUSED`` > True.  The fused reference path is bitwise-equal to
-    the multi-op path (tests/solvers pins it), so the default flips only
-    the program structure, never the arithmetic."""
-    if cfg.fused is not None:
-        return cfg.fused
-    env = os.environ.get("REPRO_FUSED")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off")
-    return True
 
 
 def _predict_current(cfg, w, b, batch: SparseBatch):

@@ -7,11 +7,11 @@ by phase without changing what runs.
 * ``GATHER``: everything read before the update — the touched rows'
   gather, and for the cache-based solvers the DP-cache extend and the
   catch-up factors;
-* ``KERNEL``: the update itself — the backend's fused whole-step op, or the
-  unfused chain (catch-up or apply-at-read, predict, gradient), and on a
-  feature mesh the margin psum;
-* ``SCATTER``: the write-back — the scatter-SET/ADD into the state, the
-  storage-grid round-trips, the bias step;
+* ``KERNEL``: the update itself — the backend's fused whole-step op, and
+  on a feature mesh the shard-local margin op, the margin psum, the
+  gradient and (for FTRL) the AdaGrad deltas;
+* ``SCATTER``: the write-back — the scatter-SET/ADD into the state and the
+  bias step;
 * ``FLUSH``: the round flush (``core.linear_trainer.flush``,
   ``dist.linear.local_flush``).
 

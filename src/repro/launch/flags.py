@@ -1,13 +1,11 @@
 """Shared CLI flag definitions for the launch drivers.
 
-``--backend``, ``--solver``, ``--fused``, ``--dim``, ``--state-dtype``,
-``--metrics-out`` and ``--profile`` mean the same thing in train.py,
-sweep.py and serve.py, but each driver used to define them independently —
-choices lists and help text drifted (train's fused flag was spelled
-``--reg-fused``, serve restricted nothing, sweep restricted state dtypes).
-Each flag now has ONE definition here; drivers customize only what
-genuinely differs (help-text focus, solver choices, extra aliases — train
-keeps ``--reg-fused`` as a documented alias of ``--fused``).
+``--backend``, ``--solver``, ``--dim``, ``--metrics-out`` and ``--profile``
+mean the same thing in train.py, sweep.py, path.py and serve.py, so each
+flag has ONE definition here; drivers customize only what genuinely
+differs (help-text focus, solver choices).  ``--fused`` is train.py's
+alone (the LM embedding's one-pass catch-up + step), with ``--reg-fused``
+as a documented alias.
 """
 from __future__ import annotations
 
@@ -54,11 +52,6 @@ def add_fused(
 ) -> None:
     """BooleanOptionalAction under dest ``fused``; every alias also gets its
     ``--no-`` form (train's ``--reg-fused`` / ``--no-reg-fused``)."""
-    if help is None:
-        help = (
-            "fused whole-step solver kernels (--no-fused: multi-op step; "
-            "default: $REPRO_FUSED, then fused)"
-        )
     ap.add_argument(
         "--fused",
         *aliases,
@@ -71,17 +64,6 @@ def add_fused(
 
 def add_dim(ap: argparse.ArgumentParser, default: int = 20_000, help: Optional[str] = None) -> None:
     ap.add_argument("--dim", type=int, default=default, help=help or "feature-space size")
-
-
-def add_state_dtype(ap: argparse.ArgumentParser, help: Optional[str] = None) -> None:
-    from repro import core as lt_core
-
-    if help is None:
-        help = (
-            "storage grid for the non-weight state columns "
-            "(psi / ftrl z,n; DESIGN.md §13)"
-        )
-    ap.add_argument("--state-dtype", default="f32", choices=tuple(lt_core.STATE_DTYPES), help=help)
 
 
 def add_mesh(ap: argparse.ArgumentParser, help: Optional[str] = None) -> None:

@@ -104,8 +104,6 @@ def main() -> None:
         help="hot-swap the best-by-loss path point into a LinearService",
     )
     flags.add_backend(ap)
-    flags.add_fused(ap)
-    flags.add_state_dtype(ap)
     flags.add_metrics_out(
         ap,
         help="write a structured JSONL run log (per-stage path.stage spans + "
@@ -129,8 +127,6 @@ def main() -> None:
         round_len=args.round_len,
         schedule=ScheduleConfig(kind="inv_sqrt", eta0=args.eta0, t0=100.0),
         backend=args.backend,
-        fused=args.fused,
-        state_dtype=args.state_dtype,
         mesh=args.mesh,
     )
     grid = make_grid(

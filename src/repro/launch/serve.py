@@ -129,8 +129,7 @@ def service_eta0(p_max: int) -> float:
 
 
 def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch=8,
-                 requests=256, round_len=256, seed=0, fused=None, state_dtype="f32",
-                 mesh=None):
+                 requests=256, round_len=256, seed=0, mesh=None):
     """Online learn/predict smoke over the LinearService: warm the complete
     jit set (every power-of-two bucket x {learn, predict} + the round
     flush), then stream ``requests`` examples and assert zero recompiles.
@@ -145,7 +144,7 @@ def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch
     cfg = LinearConfig(
         dim=dim, round_len=round_len, lam1=1e-5, lam2=1e-6,
         schedule=ScheduleConfig(kind="inv_sqrt", eta0=service_eta0(p_max), t0=100.0),
-        fused=fused, state_dtype=state_dtype, mesh=mesh,
+        mesh=mesh,
     )
     svc = LinearService(cfg, ServiceConfig(
         p_max=p_max, micro_batch=micro_batch, backend=backend, solver=solver,
@@ -203,7 +202,7 @@ def serve_linear(*, solver=None, backend=None, dim=20_000, p_max=32, micro_batch
 
 def serve_multitenant(*, tenants=8, solver=None, backend=None, dim=20_000,
                       p_max=32, micro_batch=8, requests=512, round_len=64,
-                      seed=0, fused=None, state_dtype="f32", mesh=None):
+                      seed=0, mesh=None):
     """Multi-tenant smoke over MultiLinearService: warm the complete vmapped
     program set, provision ``tenants`` tenants (a lam1 ladder — every lane
     carries its own hypers), stream tenant-tagged traffic through the
@@ -218,7 +217,7 @@ def serve_multitenant(*, tenants=8, solver=None, backend=None, dim=20_000,
     cfg = LinearConfig(
         dim=dim, round_len=round_len, lam1=1e-5, lam2=1e-6,
         schedule=ScheduleConfig(kind="inv_sqrt", eta0=service_eta0(p_max), t0=100.0),
-        fused=fused, state_dtype=state_dtype, mesh=mesh,
+        mesh=mesh,
     )
     svc = MultiLinearService(cfg, n_slots=tenants, service=ServiceConfig(
         p_max=p_max, micro_batch=micro_batch, backend=backend, solver=solver,
@@ -358,11 +357,6 @@ def main():
     )
     flags.add_backend(ap, help="kernel backend for the attention / solver hot "
                                "paths (default: $REPRO_BACKEND or platform default)")
-    flags.add_fused(ap, help="--linear: fused whole-step solver kernels "
-                             "(--no-fused: multi-op step; default: "
-                             "$REPRO_FUSED, then fused)")
-    flags.add_state_dtype(ap, help="--linear: storage grid for the non-weight "
-                                   "state columns (DESIGN.md §13)")
     flags.add_metrics_out(ap)
     flags.add_profile(ap)
     args = ap.parse_args()
@@ -383,12 +377,10 @@ def main():
                 serve_multitenant(tenants=args.tenants, solver=args.solver,
                                   backend=args.backend, dim=args.dim,
                                   requests=args.requests or 512, seed=args.seed,
-                                  fused=args.fused, state_dtype=args.state_dtype,
                                   mesh=mesh)
             else:
                 serve_linear(solver=args.solver, backend=args.backend, dim=args.dim,
                              requests=args.requests or 256, seed=args.seed,
-                             fused=args.fused, state_dtype=args.state_dtype,
                              mesh=mesh)
         return
     if not args.arch:

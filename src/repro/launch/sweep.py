@@ -86,12 +86,6 @@ def main() -> None:
         help="kernel backend for the vmapped lazy/flush hot "
         "paths (default: $REPRO_BACKEND or platform default)",
     )
-    flags.add_fused(ap)
-    flags.add_state_dtype(
-        ap,
-        help="storage grid for the non-weight state columns (psi / ftrl z,n);"
-        " bf16/int8 bound round_len for cache-based solvers (DESIGN.md §13)",
-    )
     flags.add_metrics_out(
         ap,
         help="write a structured JSONL run log (per-stage spans + compile "
@@ -115,8 +109,6 @@ def main() -> None:
         round_len=args.round_len,
         schedule=ScheduleConfig(kind="inv_sqrt", eta0=args.eta0, t0=100.0),
         backend=args.backend,
-        fused=args.fused,
-        state_dtype=args.state_dtype,
         mesh=args.mesh,
     )
     grid = make_grid(

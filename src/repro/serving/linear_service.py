@@ -39,7 +39,7 @@ class LinearService:
     def __init__(self, cfg: LinearConfig, service: Optional[ServiceConfig] = None, *,
                  w0: Optional[np.ndarray] = None):
         service = service or ServiceConfig()
-        # pin every deferred LinearConfig field (backend/solver/fused) to a
+        # pin every deferred LinearConfig field (backend/solver) to a
         # concrete value before the first jit: the live service must never
         # change program because $REPRO_*/use_backend() context changed
         cfg = pin_config(cfg, service)

@@ -236,7 +236,7 @@ class MultiLinearService:
     """N tenant linear models served through one vmapped program per solver.
 
     ``cfg`` is the *shared structure* (dim, loss, schedule kind, round_len,
-    backend, fused routing — everything that changes the program); per-
+    backend — everything that changes the program); per-
     tenant hypers (lam1, lam2, eta0) and the solver vary per tenant.
     ``n_slots`` is the capacity of each solver group; ``solvers`` names the
     groups to provision (default: the config's resolved solver only — every

@@ -15,9 +15,9 @@ with TypeError; tests/serving/test_service_config.py pins that
 `service=ServiceConfig(...)` is the only construction path.
 
 `pin_config` is the other construction-time rule both services share: a
-live service must never change its kernel backend, solver, or fused-step
-routing because trace-time context ($REPRO_BACKEND / $REPRO_SOLVER /
-$REPRO_FUSED or a `use_backend()` scope) changed under it — so every
+live service must never change its kernel backend or solver because
+trace-time context ($REPRO_BACKEND / $REPRO_SOLVER or a `use_backend()`
+scope) changed under it — so every
 deferred LinearConfig field is resolved to a concrete value exactly once,
 before the first jit is built.
 """
@@ -68,14 +68,13 @@ class ServiceConfig:
 
 def pin_config(cfg: LinearConfig, service: ServiceConfig) -> LinearConfig:
     """Resolve every deferred LinearConfig field to a concrete value for a
-    live service (backend, solver, fused routing), checking the service's
-    explicit choices against the config's.  Every jit the service builds —
+    live service (backend, solver), checking the service's explicit
+    choices against the config's.  Every jit the service builds —
     now or in a later swap rebuild — closes over the same resolved choices,
     whatever use_backend()/$REPRO_* context happens to be live when it
     first traces."""
     from repro import backend as kernel_backend
     from repro import solvers as solver_registry
-    from repro.core import linear_trainer as lt
 
     if service.backend is not None and cfg.backend is not None and service.backend != cfg.backend:
         raise ValueError(
@@ -95,8 +94,6 @@ def pin_config(cfg: LinearConfig, service: ServiceConfig) -> LinearConfig:
         cfg = dataclasses.replace(
             cfg, solver=(service.solver or solver_registry.for_config(cfg).name)
         )
-    if cfg.fused is None:
-        cfg = dataclasses.replace(cfg, fused=lt.fused_enabled(cfg))
     return cfg
 
 

@@ -23,7 +23,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import dp_caches, lazy_enet, phases, state_compress
+from repro.core import dp_caches, lazy_enet, phases
 from repro.core.dp_caches import FOBOS, SGD
 from repro.core.schedules import validate_schedule
 
@@ -38,14 +38,10 @@ def gather_touched(wpsi: jnp.ndarray, idx_f: jnp.ndarray) -> Tuple[jnp.ndarray, 
     return wpsi[idx_f, 0], wpsi[idx_f, 1].astype(jnp.int32)
 
 
-def write_back(cfg, wpsi, idx_f, w_cur, i, neg_eta_g) -> jnp.ndarray:
+def write_back(wpsi, idx_f, w_cur, i, neg_eta_g) -> jnp.ndarray:
     """Set the caught-up ``w`` and ``psi = i`` (duplicates identical), then
-    scatter-ADD the loss-gradient step (duplicates accumulate).  psi
-    round-trips its storage grid on write (exact by ``validate``; the f32
-    default is the identity)."""
-    psi_new = state_compress.roundtrip(
-        jnp.broadcast_to(i.astype(jnp.float32), w_cur.shape), cfg.state_dtype, integer=True
-    )
+    scatter-ADD the loss-gradient step (duplicates accumulate)."""
+    psi_new = jnp.broadcast_to(i.astype(jnp.float32), w_cur.shape)
     wpsi = wpsi.at[idx_f, 0].set(w_cur)
     wpsi = wpsi.at[idx_f, 1].set(psi_new)
     return wpsi.at[idx_f, 0].add(neg_eta_g)
@@ -58,11 +54,6 @@ class LazyCacheSolver(Solver):
     state_cols = 2
     caches_based = True
     has_dense = True
-
-    def validate(self, cfg) -> None:
-        # psi must survive its storage grid EXACTLY (a rounded psi indexes
-        # the wrong DP-cache slot): bf16 -> round_len <= 256, int8 -> <= 127
-        state_compress.validate_state_dtype(cfg.state_dtype, cfg.round_len, has_psi=True)
 
     # subclass hook: the truncation period (0 = regularize every step)
     def k_period(self, cfg) -> int:
@@ -87,7 +78,6 @@ class LazyCacheSolver(Solver):
     def touched_update(self, cfg, state, batch, hp, eta, bk) -> Tuple[object, jnp.ndarray]:
         from repro.core import linear_trainer as lt
 
-        fused = lt.fused_enabled(cfg)
         with jax.named_scope(phases.GATHER):
             # O(1): fill DP cache slot i+1 with this step's eta (Lemma 1 / Thm 1-2)
             caches = self.extend_caches(
@@ -96,36 +86,27 @@ class LazyCacheSolver(Solver):
             idx_f = batch.idx.reshape(-1)
             w_g, psi_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
-            if fused:
-                # (ratio, shift) from the caches in XLA — tiny O(B*p) gathers
-                # + exps, and where a traced per-config lam1 enters
-                ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
+            # (ratio, shift) from the caches in XLA — tiny O(B*p) gathers
+            # + exps, and where a traced per-config lam1 enters
+            ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
         with jax.named_scope(phases.KERNEL):
-            if fused:
-                # ONE whole-step tile pass: catch-up, predict, gradient,
-                # update delta
-                w_cur2, delta, gz, loss = bk.fused_step(
-                    w_g.reshape(shape),
-                    ratio.reshape(shape),
-                    jnp.broadcast_to(shift, ratio.shape).reshape(shape),
-                    batch.val,
-                    batch.y,
-                    state.b,
-                    eta,
-                    loss=cfg.loss,
-                    use_bias=cfg.use_bias,
-                )
-                w_cur = w_cur2.reshape(-1)
-                neg_eta_g = delta.reshape(-1)  # [B*p]
-            else:
-                # --- lazy catch-up of touched weights: reg for tau in [psi, i) ---
-                w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
-                # --- predict with current weights, loss gradient ---
-                z = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
-                loss, gz = lt._grad_z(cfg, z, batch.y)
-                neg_eta_g = -eta * (gz[:, None] * batch.val).reshape(-1)  # [B*p]
+            # ONE whole-step tile pass: catch-up, predict, gradient,
+            # update delta
+            w_cur2, delta, gz, loss = bk.fused_step(
+                w_g.reshape(shape),
+                ratio.reshape(shape),
+                jnp.broadcast_to(shift, ratio.shape).reshape(shape),
+                batch.val,
+                batch.y,
+                state.b,
+                eta,
+                loss=cfg.loss,
+                use_bias=cfg.use_bias,
+            )
+            w_cur = w_cur2.reshape(-1)
+            neg_eta_g = delta.reshape(-1)  # [B*p]
         with jax.named_scope(phases.SCATTER):
-            wpsi = write_back(cfg, state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
+            wpsi = write_back(state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         # reg for step i itself stays pending (applied at next touch / flush)
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
@@ -140,7 +121,6 @@ class LazyCacheSolver(Solver):
         from repro.core import linear_trainer as lt
         from repro.dist import linear as dl
 
-        fused = lt.fused_enabled(cfg)
         with jax.named_scope(phases.GATHER):
             caches = self.extend_caches(
                 state.caches, state.i, eta, hp.lam2, k_period=self.k_period(cfg)
@@ -149,21 +129,16 @@ class LazyCacheSolver(Solver):
             # clip-gather; sentinel lanes are masked by their zero values
             w_g, psi_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
-            if fused:
-                ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
+            ratio, shift = lazy_enet.catchup_factors(psi_g, state.i, caches, hp.lam1)
         with jax.named_scope(phases.KERNEL):
-            if fused:
-                # shard-local fused pass: catch-up + masked margin contributions
-                w_cur2, contrib = bk.fused_margin(
-                    w_g.reshape(shape),
-                    ratio.reshape(shape),
-                    jnp.broadcast_to(shift, ratio.shape).reshape(shape),
-                    batch.val,
-                )
-                w_cur = w_cur2.reshape(-1)
-            else:
-                w_cur = bk.catchup_rows(w_g, psi_g, state.i, caches, hp.lam1)
-                contrib = w_cur.reshape(shape) * batch.val
+            # shard-local fused pass: catch-up + masked margin contributions
+            w_cur2, contrib = bk.fused_margin(
+                w_g.reshape(shape),
+                ratio.reshape(shape),
+                jnp.broadcast_to(shift, ratio.shape).reshape(shape),
+                batch.val,
+            )
+            w_cur = w_cur2.reshape(-1)
             # --- the ONLY cross-shard traffic: the per-example margin ---
             z = dl.margin_psum(cfg, contrib)
             if cfg.use_bias:
@@ -171,7 +146,7 @@ class LazyCacheSolver(Solver):
             loss, gz = lt._grad_z(cfg, z, batch.y)
             neg_eta_g = (-eta * (gz[:, None] * batch.val)).reshape(-1)  # [B*p]
         with jax.named_scope(phases.SCATTER):
-            wpsi = write_back(cfg, state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
+            wpsi = write_back(state.wpsi, idx_f, w_cur, state.i, neg_eta_g)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
@@ -215,7 +190,6 @@ class DPSolver(LazyCacheSolver):
         self.name = flavor
 
     def validate(self, cfg) -> None:
-        super().validate(cfg)  # psi storage-grid bound (state_dtype)
         # the eta*lam2 < 1 divergence check is SGD-specific; FoBoS is
         # unconditionally valid (validate_schedule returns early for it)
         validate_schedule(cfg.schedule.make(), cfg.lam2, self.name, horizon=10_000_000)

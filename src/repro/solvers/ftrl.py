@@ -47,9 +47,24 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import phases, state_compress
+from repro.core import phases
 
 from .api import Solver
+
+
+def gather_touched(wpsi: jnp.ndarray, idx_f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(z, n)`` of the touched features ``idx_f`` ([B*p] each), from one
+    gather of their ``(w, z, n)`` rows."""
+    g3 = wpsi[idx_f]
+    return g3[:, 1], g3[:, 2]
+
+
+def write_back(wpsi, idx_f, dz, dn) -> jnp.ndarray:
+    """Scatter-ADD the ``(dz, dn)`` deltas (duplicates accumulate).  The w
+    column stays stale: reads always derive from ``(z, n)``, and the flush
+    rematerializes it."""
+    wpsi = wpsi.at[idx_f, 1].add(dz)
+    return wpsi.at[idx_f, 2].add(dn)
 
 
 class FTRLSolver(Solver):
@@ -59,10 +74,6 @@ class FTRLSolver(Solver):
     has_dense = False
 
     def validate(self, cfg) -> None:
-        # no psi column: every state_dtype is admissible at any round_len
-        # (z/n take the lossy float grid; the error bound is documented in
-        # DESIGN.md §13 and pinned by tests/fused)
-        state_compress.validate_state_dtype(cfg.state_dtype, cfg.round_len, has_psi=False)
         if cfg.ftrl_beta <= 0.0:
             raise ValueError(f"ftrl needs beta > 0, got {cfg.ftrl_beta}")
         if cfg.schedule.eta0 <= 0.0:
@@ -104,47 +115,27 @@ class FTRLSolver(Solver):
         alpha = jnp.asarray(hp.eta_scale, jnp.float32)
         with jax.named_scope(phases.GATHER):
             idx_f = batch.idx.reshape(-1)
-            g3 = state.wpsi[idx_f]  # [B*p, 3] single gather: (w, z, n) rows
-            z_g, n_g = g3[:, 1], g3[:, 2]
+            z_g, n_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
         with jax.named_scope(phases.KERNEL):
-            if lt.fused_enabled(cfg):
-                # ONE whole-step tile pass: apply-at-read weights, predict,
-                # loss gradient, AdaGrad deltas (backend.ftrl_fused_step)
-                _, dz2, dn2, gz, loss = bk.ftrl_fused_step(
-                    z_g.reshape(shape),
-                    n_g.reshape(shape),
-                    batch.val,
-                    batch.y,
-                    state.b,
-                    alpha,
-                    cfg.ftrl_beta,
-                    hp.lam1,
-                    hp.lam2,
-                    loss=cfg.loss,
-                    use_bias=cfg.use_bias,
-                )
-                dz, dn = dz2.reshape(-1), dn2.reshape(-1)
-            else:
-                # apply-at-read: current weights straight from (z, n) — no catch-up
-                w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
-                zlin = lt._predict_current(cfg, w_cur.reshape(shape), state.b, batch)
-                loss, gz = lt._grad_z(cfg, zlin, batch.y)
-                g_w = (gz[:, None] * batch.val).reshape(-1)  # [B*p]
-                dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
+            # ONE whole-step tile pass: apply-at-read weights, predict,
+            # loss gradient, AdaGrad deltas (backend.ftrl_fused_step)
+            _, dz2, dn2, gz, loss = bk.ftrl_fused_step(
+                z_g.reshape(shape),
+                n_g.reshape(shape),
+                batch.val,
+                batch.y,
+                state.b,
+                alpha,
+                cfg.ftrl_beta,
+                hp.lam1,
+                hp.lam2,
+                loss=cfg.loss,
+                use_bias=cfg.use_bias,
+            )
+            dz, dn = dz2.reshape(-1), dn2.reshape(-1)
         with jax.named_scope(phases.SCATTER):
-            # scatter-ADD deltas (duplicates accumulate); the w column stays
-            # stale — reads always derive from (z, n), flush rematerializes it
-            wpsi = state.wpsi.at[idx_f, 1].add(dz)
-            wpsi = wpsi.at[idx_f, 2].add(dn)
-            if cfg.state_dtype != "f32":
-                # compress-on-write (DESIGN.md §13): the touched (z, n) rows
-                # round-trip the storage grid AFTER the scatter-ADD settles —
-                # duplicate gathers see identical final values, so the
-                # scatter-SET of the round-tripped image stays consistent
-                zn = wpsi[idx_f]
-                wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
-                wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
+            wpsi = write_back(state.wpsi, idx_f, dz, dn)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=state.caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)
@@ -161,19 +152,20 @@ class FTRLSolver(Solver):
         alpha = jnp.asarray(hp.eta_scale, jnp.float32)
         with jax.named_scope(phases.GATHER):
             idx_f = batch.idx.reshape(-1)
-            g3 = state.wpsi[idx_f]  # [B*p, 3] clip-gather; sentinel rows masked
-            z_g, n_g = g3[:, 1], g3[:, 2]
+            # clip-gather; sentinel rows are masked by their zero values
+            z_g, n_g = gather_touched(state.wpsi, idx_f)
             shape = batch.idx.shape
         with jax.named_scope(phases.KERNEL):
-            if lt.fused_enabled(cfg):
-                w_cur2, contrib = bk.ftrl_margin(
-                    z_g.reshape(shape), n_g.reshape(shape), batch.val,
-                    alpha, cfg.ftrl_beta, hp.lam1, hp.lam2,
-                )
-                w_cur = w_cur2.reshape(-1)
-            else:
-                w_cur = bk.ftrl_read(z_g, n_g, alpha, cfg.ftrl_beta, hp.lam1, hp.lam2)
-                contrib = w_cur.reshape(shape) * batch.val
+            w_cur2, contrib = bk.ftrl_margin(
+                z_g.reshape(shape),
+                n_g.reshape(shape),
+                batch.val,
+                alpha,
+                cfg.ftrl_beta,
+                hp.lam1,
+                hp.lam2,
+            )
+            w_cur = w_cur2.reshape(-1)
             # --- the ONLY cross-shard traffic: the per-example margin ---
             zlin = dl.margin_psum(cfg, contrib)
             if cfg.use_bias:
@@ -182,12 +174,7 @@ class FTRLSolver(Solver):
             g_w = (gz[:, None] * batch.val).reshape(-1)  # masked: 0 off-shard
             dz, dn = bk.ftrl_update(w_cur, n_g, g_w, alpha)
         with jax.named_scope(phases.SCATTER):
-            wpsi = state.wpsi.at[idx_f, 1].add(dz)
-            wpsi = wpsi.at[idx_f, 2].add(dn)
-            if cfg.state_dtype != "f32":
-                zn = wpsi[idx_f]
-                wpsi = wpsi.at[idx_f, 1].set(state_compress.roundtrip(zn[:, 1], cfg.state_dtype))
-                wpsi = wpsi.at[idx_f, 2].set(state_compress.roundtrip(zn[:, 2], cfg.state_dtype))
+            wpsi = write_back(state.wpsi, idx_f, dz, dn)
             b = state.b - eta * jnp.sum(gz) if cfg.use_bias else state.b
         new = lt.LinearState(wpsi=wpsi, b=b, caches=state.caches, i=state.i + 1, t=state.t + 1)
         return new, jnp.mean(loss)

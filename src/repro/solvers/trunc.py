@@ -45,7 +45,6 @@ class TruncSolver(LazyCacheSolver):
         return cfg.trunc_k
 
     def validate(self, cfg) -> None:
-        super().validate(cfg)  # psi storage-grid bound (state_dtype)
         k = cfg.trunc_k
         if k < 1:
             raise ValueError(f"trunc solver needs trunc_k >= 1, got {k}")

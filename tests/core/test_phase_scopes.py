@@ -33,8 +33,8 @@ def _batches(seed=0):
     )
 
 
-def _cfg(solver, **kw):
-    return LinearConfig(dim=DIM, solver=solver, round_len=R, lam1=1e-3, lam2=1e-4, **kw)
+def _cfg(solver):
+    return LinearConfig(dim=DIM, solver=solver, round_len=R, lam1=1e-3, lam2=1e-4)
 
 
 def _core(cfg):
@@ -68,12 +68,6 @@ def _scopes(fn, args) -> set:
 @pytest.mark.parametrize("factory", sorted(FACTORIES))
 def test_round_program_names_the_four_phases(factory, solver):
     fn, args = FACTORIES[factory](_cfg(solver))
-    assert _scopes(fn, args) == set(phases.PHASES)
-
-
-@pytest.mark.parametrize("solver", ["fobos", "trunc", "ftrl"])
-def test_unfused_step_names_the_four_phases(solver):
-    fn, args = _core(_cfg(solver, fused=False, trunc_k=2))
     assert _scopes(fn, args) == set(phases.PHASES)
 
 

@@ -69,7 +69,6 @@ def test_pin_config_resolves_and_rejects_conflicts():
     pinned = pin_config(_cfg(), ServiceConfig())
     assert pinned.backend is not None
     assert pinned.solver is not None
-    assert pinned.fused is not None
     # explicit-vs-explicit disagreements are errors, not silent overrides
     with pytest.raises(ValueError, match="conflicting explicit solvers"):
         pin_config(_cfg(solver="sgd"), ServiceConfig(solver="ftrl"))

@@ -44,12 +44,12 @@ def _sweep(cfg):
 STEPS = {"single": _single, "sweep": _sweep}
 
 
-@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
 @pytest.mark.parametrize("solver", ["sgd", "fobos", "trunc"])
 @pytest.mark.parametrize("step", sorted(STEPS))
-def test_lazy_step_touches_the_state_by_column(step, solver, fused):
+def test_lazy_step_touches_the_state_by_column(step, solver, backend):
     cfg = LinearConfig(
-        dim=DIM, solver=solver, fused=fused, round_len=4, lam1=1e-3, lam2=1e-4, trunc_k=2
+        dim=DIM, solver=solver, backend=backend, round_len=4, lam1=1e-3, lam2=1e-4, trunc_k=2
     )
     fn, args = STEPS[step](cfg)
     accesses = state_accesses(jax.make_jaxpr(fn)(*args).jaxpr, DIM, 2)
